@@ -135,6 +135,8 @@ def main(argv: list[str] | None = None) -> None:
     p.add_argument("--smoke", action="store_true",
                    help="CI-sized shapes for every suite")
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.suite in ("kernels", "all"):
         suite_kernels(smoke=args.smoke)

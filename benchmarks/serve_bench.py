@@ -257,7 +257,17 @@ def _run_sharded_variant(name: str, extra_cli: list[str], *,
                          trace_cli: list[str], tmpdir: str) -> dict:
     """One ``launch/serve.py`` run in a forced-2-device subprocess (the
     XLA device-count flag must be set before jax initialises, which this
-    already-running process is long past) — returns its ``--stats-json``."""
+    already-running process is long past) — returns its ``--stats-json``.
+
+    The child runs on forced host CPU devices, so on an accelerator host it
+    would time the CPU under the accelerator's name: refuse there."""
+    from repro.kernels.runtime import on_tpu
+    if on_tpu():
+        raise RuntimeError(
+            f"serve_sharded variant {name!r} runs on forced host CPU devices "
+            f"in a child process; on this TPU host it would measure the CPU, "
+            f"not the chips.  Run the row on a CPU-only host, or drive the "
+            f"mesh on the chips in one process (chip_smoke.py --four-chips)")
     out = os.path.join(tmpdir, f"{name}.json")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)

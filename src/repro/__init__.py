@@ -1,13 +1,1 @@
-"""HyPar-on-JAX reproduction package.
-
-One global knob: sharding-invariant RNG.  The whole system assumes that
-``jax.random`` produces the same values whether a computation runs eagerly
-on one device or jitted over a mesh (init parity across executors, elastic
-checkpoint restore onto different meshes).  Newer jax defaults
-``jax_threefry_partitionable`` to True; older versions (< 0.5) default to
-False, under which sharded RNG silently diverges from eager RNG — so pin it
-here, before any key is ever split.
-"""
-import jax as _jax
-
-_jax.config.update("jax_threefry_partitionable", True)
+"""HyPar-on-JAX reproduction package."""

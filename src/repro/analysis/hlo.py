@@ -29,15 +29,10 @@ __all__ = ["HloAnalysis", "analyze_hlo", "CollectiveStats", "xla_cost_analysis"]
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalise ``jax.stages.Compiled.cost_analysis()`` across jax versions.
+    """``jax.stages.Compiled.cost_analysis()`` as a dict (``{}`` when the
+    backend reports none)."""
+    return dict(compiled.cost_analysis() or {})
 
-    Older jaxlibs return a list with one dict per partition; newer ones
-    return the dict directly.  Returns ``{}`` when unavailable.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8, "c64": 8,

@@ -270,7 +270,8 @@ def jacobi_hypar(A, b, *, iters: int = 500, tol: float = 0.0,
     res = float(jnp.linalg.norm(b - A @ jnp.asarray(x)))  # exact, untimed
     return JacobiResult(x, k + 1, res, dt,
                         extra={"report": report.summary(), "mode": mode,
-                               "moved_bytes": report.moved_bytes})
+                               "moved_bytes": report.moved_bytes,
+                               "job_devices": report.job_devices})
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,8 @@ callables and device handles that don't pickle).
 
 Deliberately **jax-free** (like :mod:`repro.core.store`): spawn children pay
 full import cost per process, and the numpy-level worker functions need no
-device.  Anything jax-flavoured belongs on the master side.
+device.  A function table that does import jax runs on the host CPU: the
+master spawns every child with ``JAX_PLATFORMS=cpu``.
 
 Protocol (one request/response pair in flight per worker — the master's
 per-worker dispatch queues already serialise placements per worker):

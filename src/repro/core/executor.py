@@ -78,6 +78,8 @@ class SegmentReport:
     speculated_jobs: list[str] = dataclasses.field(default_factory=list)
     # jobs served from a durable JobStore instead of executing (ProcessExecutor)
     memoised_jobs: list[str] = dataclasses.field(default_factory=list)
+    # device each executed job ran on (job name -> worker device)
+    job_devices: dict[str, Any] = dataclasses.field(default_factory=dict)
     sim_makespan: float = 0.0
     wall_time: float = 0.0
 
@@ -103,6 +105,10 @@ class ExecutionReport:
     @property
     def memoised_jobs(self) -> list[str]:
         return [j for s in self.segments for j in s.memoised_jobs]
+
+    @property
+    def job_devices(self) -> dict[str, Any]:
+        return {j: d for s in self.segments for j, d in s.job_devices.items()}
 
     def summary(self) -> str:
         return (f"mode={self.mode} segments={len(self.segments)} "
@@ -267,6 +273,7 @@ class LocalExecutor(BaseExecutor):
         rf = self.registry[job.fn]
         with self._lock:
             inputs = self._resolve_inputs(job, graph, report, worker)
+            report.job_devices[job.name] = worker.device
         t0 = time.perf_counter()
         if rf.kind == FunctionKind.CHUNKWISE:
             if not inputs:

@@ -18,10 +18,13 @@ lineage recovery) but backs each :class:`Worker` slot with a real
   backoff: a lost/silent worker's in-flight jobs are re-placed on live
   workers, and the monitor spawns a replacement process for the dead slot.
 
-Worker processes never import jax (see ``_procworker_child``): they resolve
-a numpy-level function table from a ``"module:attr"`` spec.  The master
-keeps its normal registry for job *kinds* and for control functions, which
-still run on the host.
+Worker processes are host workers (see ``_procworker_child``): they
+resolve a numpy-level function table from a ``"module:attr"`` spec, and
+they are spawned with ``JAX_PLATFORMS=cpu``, so a table that does use jax
+(``repro.train.procfns``) runs on the host CPU and never contends for an
+accelerator the master holds (an accelerator belongs to one process at a
+time).  The master keeps its normal registry for job *kinds* and for control
+functions, which still run on the host.
 
 Because every process result is sent back **and** persisted, a worker death
 loses only its in-flight jobs — the paper's ``no_send_back`` recompute cost
@@ -29,8 +32,10 @@ loses only its in-flight jobs — the paper's ``no_send_back`` recompute cost
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import multiprocessing
+import os
 import queue
 import tempfile
 import threading
@@ -47,6 +52,27 @@ from .scheduler import CostModelParams, VirtualCluster, Worker
 from .store import JobStore, job_key
 
 __all__ = ["ProcessExecutor", "WorkerFunctionError"]
+
+
+_SPAWN_ENV_LOCK = threading.Lock()
+
+
+@contextlib.contextmanager
+def _host_only_env():
+    """Pin spawn children to the CPU: a spawn child inherits ``os.environ``
+    as it stands when ``start()`` runs, so set ``JAX_PLATFORMS=cpu`` around
+    the start and restore it after.  The master's own jax read its platform
+    list at import and is unaffected."""
+    with _SPAWN_ENV_LOCK:
+        old = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            yield
+        finally:
+            if old is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = old
 
 
 class WorkerFunctionError(RuntimeError):
@@ -131,7 +157,8 @@ class ProcessExecutor(LocalExecutor):
             args=(wid, self.jobstore.path, self.worker_fns,
                   self.heartbeat_interval_s, req_q, resp_q),
             daemon=True, name=f"hypar-proc-w{wid}")
-        p.start()
+        with _host_only_env():
+            p.start()
         ph = _ProcHandle(wid, p, req_q, resp_q)
         self.procs[wid] = ph
         self._hb.register(wid)

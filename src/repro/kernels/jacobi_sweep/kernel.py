@@ -15,12 +15,14 @@ Two variants:
 * :func:`jacobi_sweep_residual_kernel` — **fused-residual** sweep.  On the
   last col step the accumulator holds ``A·x`` for the row block, so the
   residual of the *incoming* iterate, ``r = b - A·x``, is already in VMEM:
-  the kernel emits both ``x' = x + r / d`` and the per-row-block partial
-  sums ``Σ r²`` in the same pass.  The caller reduces the partials to
-  ``‖b - A·x‖²`` outside the kernel.  A convergence loop built on this
-  needs exactly **one** A-matvec per iteration (the residual it tests is
-  lagged by one iteration — standard for fused Jacobi/Richardson loops),
-  halving the memory traffic of the sweep+residual pair.
+  the kernel emits both ``x' = x + r / d`` and the per-row squares ``r²``
+  in the same pass.  The caller reduces them to ``‖b - A·x‖²`` outside the
+  kernel (an (rb, 1) output block tiles like x'; a per-row-block (1, 1)
+  partial breaks the chip's (8, 128) block tiling).  A convergence loop
+  built on this needs exactly **one** A-matvec per iteration (the residual
+  it tests is lagged by one iteration — standard for fused
+  Jacobi/Richardson loops), halving the memory traffic of the
+  sweep+residual pair.
 """
 from __future__ import annotations
 
@@ -30,6 +32,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# full float32 products: at default precision the chip's matrix unit rounds
+# f32 operands to bfloat16, and the solver then stalls near a residual of
+# 2^-9 relative instead of converging like its float32 reference
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _jacobi_kernel(a_ref, x_ref, b_ref, diag_ref, xr_ref, o_ref, acc, *,
@@ -43,6 +50,7 @@ def _jacobi_kernel(a_ref, x_ref, b_ref, diag_ref, xr_ref, o_ref, acc, *,
     a = a_ref[...].astype(jnp.float32)            # (rb, cb)
     x = x_ref[...].astype(jnp.float32)            # (cb, 1)
     acc[...] += jax.lax.dot_general(a, x, (((1,), (0,)), ((), ())),
+                                    precision=_F32,
                                     preferred_element_type=jnp.float32)
 
     @pl.when(ci == n_col_blocks - 1)
@@ -90,6 +98,7 @@ def _jacobi_fused_kernel(a_ref, x_ref, b_ref, diag_ref, xr_ref, o_ref, p_ref,
     a = a_ref[...].astype(jnp.float32)            # (rb, cb)
     x = x_ref[...].astype(jnp.float32)            # (cb, 1)
     acc[...] += jax.lax.dot_general(a, x, (((1,), (0,)), ((), ())),
+                                    precision=_F32,
                                     preferred_element_type=jnp.float32)
 
     @pl.when(ci == n_col_blocks - 1)
@@ -99,7 +108,7 @@ def _jacobi_fused_kernel(a_ref, x_ref, b_ref, diag_ref, xr_ref, o_ref, p_ref,
         xr = xr_ref[...].astype(jnp.float32)      # (rb, 1)
         r = b - acc[...]                          # residual rows of incoming x
         o_ref[...] = (xr + r / d).astype(o_ref.dtype)
-        p_ref[...] = jnp.sum(r * r).reshape(1, 1)
+        p_ref[...] = r * r
 
 
 def jacobi_sweep_residual_kernel(A, x, b, diag, *, row_block: int = 256,
@@ -107,8 +116,9 @@ def jacobi_sweep_residual_kernel(A, x, b, diag, *, row_block: int = 256,
                                  interpret: bool = False):
     """Fused sweep: returns ``(x', partials)`` in one A-pass.
 
-    ``partials`` has shape (row_blocks, 1) fp32; ``partials.sum()`` is
-    ``‖b - A·x‖²`` — the squared residual of the *input* iterate.
+    ``partials`` has shape (N, 1) fp32, one ``r²`` per row;
+    ``partials.sum()`` is ``‖b - A·x‖²`` — the squared residual of the
+    *input* iterate.
     """
     N = A.shape[0]
     rb, cb = min(row_block, N), min(col_block, N)
@@ -126,11 +136,11 @@ def jacobi_sweep_residual_kernel(A, x, b, diag, *, row_block: int = 256,
         ],
         out_specs=[
             pl.BlockSpec((rb, 1), lambda r, c: (r, 0)),
-            pl.BlockSpec((1, 1), lambda r, c: (r, 0)),
+            pl.BlockSpec((rb, 1), lambda r, c: (r, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((N, 1), x.dtype),
-            jax.ShapeDtypeStruct((N // rb, 1), jnp.float32),
+            jax.ShapeDtypeStruct((N, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((rb, 1), jnp.float32)],
         interpret=interpret,

@@ -8,11 +8,16 @@ view.  The kernel returns unnormalised (acc, m, l); the current token's
 self term is LSE-merged here so the delta-cache write contract of
 ``models.attention`` is untouched.
 
-Impl resolution differs from :func:`runtime.resolve_impl` in ONE case:
+Impl resolution differs from :func:`runtime.resolve_impl` in two ways:
 ``auto`` off-TPU resolves to ``ref`` (the gather oracle), not interpret —
 decode runs every step of every serve trace, and the Pallas interpreter is
 orders of magnitude too slow to be a serving default.  Tests opt into
-``interpret`` explicitly so CPU CI still exercises the kernel body.
+``interpret`` explicitly so CPU CI still exercises the kernel body.  And
+the kernel reads the pool in the query's dtype, so a pool stored in another
+dtype (f8 KV) resolves ``auto`` to ``ref``, and an explicit ``kernel`` or
+``interpret`` request for it raises instead of quietly running the ref.
+The same holds for a step compiled over a multi-device mesh, which the
+compiler cannot partition the kernel into.
 """
 from __future__ import annotations
 
@@ -29,12 +34,32 @@ DEFAULT_HEAD_BLOCK = 1
 _SUBLANE = 8   # grouped-q axis padded to the f32 sublane tile
 
 
-def resolve_paged_impl(impl: str) -> str:
+def resolve_paged_impl(impl: str, kv_dtype=None, q_dtype=None, *,
+                       partitioned: bool = False) -> str:
+    """The impl a decode step runs for ``impl`` on this backend, given the
+    pool's and the query's dtypes (unknown dtypes are taken to match).
+
+    ``partitioned``: the step is compiled over a mesh of more than one
+    device.  The compiler cannot partition a Mosaic kernel (it asks for a
+    ``shard_map``, which this path does not have yet), so ``auto`` resolves
+    to ``ref`` there and an explicit ``kernel`` raises."""
     if impl not in IMPLS:
         raise ValueError(f"unknown impl {impl!r}; pick from {IMPLS}")
-    if impl != "auto":
-        return impl
-    return "kernel" if on_tpu() else "ref"
+    same_dtype = (kv_dtype is None or q_dtype is None
+                  or jnp.dtype(kv_dtype) == jnp.dtype(q_dtype))
+    if impl == "auto":
+        return ("kernel" if on_tpu() and same_dtype and not partitioned
+                else "ref")
+    if impl != "ref" and not same_dtype:
+        raise ValueError(
+            f"paged attention impl {impl!r} reads the KV pool in the query "
+            f"dtype {jnp.dtype(q_dtype).name}, but the pool is "
+            f"{jnp.dtype(kv_dtype).name}; use 'ref' or 'auto'")
+    if impl == "kernel" and partitioned:
+        raise ValueError("the paged attention kernel cannot run on a mesh of "
+                         "more than one device (the compiler does not "
+                         "partition Mosaic kernels); use 'ref' or 'auto'")
+    return impl
 
 
 def paged_decode_attention(q, k_pool, v_pool, pages, kv_len, kt, vt, *,
@@ -51,9 +76,7 @@ def paged_decode_attention(q, k_pool, v_pool, pages, kv_len, kt, vt, *,
     ``head_block`` (kv heads per grid step) comes from the autotune cache
     when unset — a cache-only, trace-safe lookup like the other kernels.
     """
-    impl = resolve_paged_impl(impl)
-    if impl != "ref" and k_pool.dtype != q.dtype:
-        impl = "ref"   # f8-stored pools: the ref path casts the layer slice
+    impl = resolve_paged_impl(impl, k_pool.dtype, q.dtype)
     if impl == "ref":
         return paged_decode_attention_ref(q, k_pool, v_pool, pages, kv_len,
                                           kt, vt, window=window)

@@ -3,7 +3,7 @@
 Computes, per (batch·chunk, head), the dual quadratic form of the SSD
 algorithm — the compute hot-spot of Mamba-2:
 
-    cum   = cumsum(a)                                  (Q,)
+    cum   = cumsum(a)        (outside the kernel)      (Q,)
     L     = tril(exp(cum_t - cum_s))                   (Q, Q)
     M     = (C Bᵀ) ⊙ L                                 (Q, Q)
     y     = M (dt ⊙ x)                                 (Q, P)
@@ -26,16 +26,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_ref):
-    # x: (1,1,Q,P) dt/a: (1,1,Q,1) b/c: (1,Q,N)
+def _ssd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, s_ref):
+    # x: (1,1,Q,P) dt/cum: (1,1,Q,1) b/c: (1,Q,N)
     x = x_ref[0, 0].astype(jnp.float32)          # (Q, P)
     dt = dt_ref[0, 0].astype(jnp.float32)        # (Q, 1)
-    a = a_ref[0, 0].astype(jnp.float32)          # (Q, 1)
+    cum = cum_ref[0, 0]                          # (Q, 1)  cumsum(a), fp32
     B = b_ref[0].astype(jnp.float32)             # (Q, N)
     C = c_ref[0].astype(jnp.float32)             # (Q, N)
     Q = x.shape[0]
 
-    cum = jnp.cumsum(a, axis=0)                  # (Q, 1)
+    # cum arrives precomputed: Pallas TPU has no cumsum lowering
     seg = cum - cum.reshape(1, Q)                # (Q, Q)  cum_t - cum_s
     rows = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
@@ -59,6 +59,7 @@ def ssd_intra_chunk_kernel(xh, dt, a, Bm, Cm, *, interpret: bool = False):
 
     Returns (y_intra (BC,H,Q,P), S_chunk (BC,H,N,P))."""
     BC, H, Q, P = xh.shape
+    cum = jnp.cumsum(a.astype(jnp.float32), axis=2)
     N = Bm.shape[-1]
     grid = (BC, H)
     return pl.pallas_call(
@@ -80,4 +81,4 @@ def ssd_intra_chunk_kernel(xh, dt, a, Bm, Cm, *, interpret: bool = False):
             jax.ShapeDtypeStruct((BC, H, N, P), jnp.float32),
         ],
         interpret=interpret,
-    )(xh, dt, a, Bm, Cm)
+    )(xh, dt, cum, Bm, Cm)

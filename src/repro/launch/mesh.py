@@ -12,31 +12,23 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["compat_make_mesh", "make_production_mesh", "make_test_mesh"]
+__all__ = ["make_production_mesh", "make_test_mesh"]
 
 
-def compat_make_mesh(shape, axis_names):
-    """``jax.make_mesh`` across jax versions.
-
-    Newer jax wants explicit ``axis_types=(AxisType.Auto, ...)`` for
-    GSPMD-propagated shardings; older jax (< 0.5) has no ``AxisType`` and
-    defaults to the same behaviour.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
+def _mesh(shape, axis_names):
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: int = 1):
     """Small mesh over however many (host) devices the process has."""
     if pod > 1:
-        return compat_make_mesh((pod, data, model), ("pod", "data", "model"))
-    return compat_make_mesh((data, model), ("data", "model"))
+        return _mesh((pod, data, model), ("pod", "data", "model"))
+    return _mesh((data, model), ("data", "model"))

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import (Engine, HyParRequestTracker, PagedEngine, Request,
                          RequestQueue, SamplingParams, ServeScheduler,
                          count_generated)
@@ -296,6 +297,11 @@ def trace_stats(args, sched, snap) -> dict:
         "lat_p95_s": float(np.percentile(lats, 95)),
         "occupancy": occupancy,
         "trace_counts": trace_counts,
+        # the decode attention the run actually executed (PagedEngine
+        # resolves "auto" from the backend and the pool dtype)
+        "paged_attn_impl": eng.attn_impl if sched.paged else None,
+        # generated ids per request: what mesh/impl parity checks compare
+        "outputs": {int(r.rid): [int(t) for t in r.tokens] for r in results},
         "reserve": getattr(sched, "reserve", "lifetime"),
         "preempt_count": n_preempted,
         "resume_tokens_recomputed": resume_recomputed,
@@ -536,6 +542,7 @@ def main(argv=None):
         ap.error("--store-gc/--store-gc-rows need --store (nothing to "
                  "prune otherwise)")
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     from repro.models.transformer import init_params
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
@@ -547,7 +554,9 @@ def main(argv=None):
         print(f"engine={stats['engine']} ({kind}) "
               f"requests={stats['n_requests']} "
               f"(+{stats['n_rejected']} shed) tokens={stats['gen_tokens']} "
-              f"traces={stats['trace_counts']}")
+              f"traces={stats['trace_counts']}"
+              + (f" paged_attn_impl={stats['paged_attn_impl']}"
+                 if stats["paged"] else ""))
         if stats.get("mesh"):
             occ = ", ".join(f"{x*100:.0f}%" for x in stats["group_occupancy"])
             print(f"mesh={stats['mesh']} groups={stats['device_groups']} "

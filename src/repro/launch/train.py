@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
 from repro.configs import ARCHS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data import DataConfig, Prefetcher, SyntheticLMStream
 from repro.models.config import ModelConfig
 from repro.optim import OptimizerSpec, cosine_schedule
@@ -116,13 +117,14 @@ def main(argv=None):
         ap.error("--store-gc/--store-gc-rows need --store (a temporary "
                  "store is deleted whole when the run ends)")
 
+    enable_compile_cache()
     base = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = base if args.smoke else scale_config(
         base, layers=args.layers, d_model=args.d_model, seq=args.seq)
     n_dev = len(jax.devices())
     data_ax = args.data_axis or max(1, n_dev // args.model_axis)
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((data_ax, args.model_axis), ("data", "model"))
+    mesh = jax.make_mesh((data_ax, args.model_axis), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = ShardingRules(mesh=mesh, rules=dict(DEFAULT_RULES))
 
     spec = OptimizerSpec(kind=cfg.optimizer, lr=args.lr)

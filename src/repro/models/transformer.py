@@ -633,7 +633,9 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens, *,
     # applies them afterwards into the donated input buffers.
     from repro.kernels.paged_attention import resolve_paged_impl
     use_paged_kernel = (pages is not None and S == 1
-                        and resolve_paged_impl(paged_impl) != "ref")
+                        and resolve_paged_impl(
+                            paged_impl, cfg.kv_cache_dtype or cfg.compute_dtype,
+                            cfg.compute_dtype) != "ref")
 
     def group_body(carry, xs):
         h = carry

@@ -42,7 +42,9 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.kernels.paged_attention import resolve_paged_impl
 from repro.models.config import ModelConfig
 from repro.parallel.sharding import ShardingRules, use_rules
 from repro.models.transformer import (chunk_prefill_step, decode_step,
@@ -365,11 +367,15 @@ class PagedEngine:
         self.cfg, self.params = cfg, params
         self.batch, self.max_len = batch, max_len
         self.page_size = page_size
-        # paged flash-decode attention impl (DESIGN.md §15): "auto" runs
-        # the Pallas kernel on TPU and the gather_pages oracle elsewhere;
-        # "interpret" forces the kernel body through the Pallas interpreter
-        # (tests), "ref" pins the gather path
-        self.attn_impl = attn_impl
+        # paged flash-decode attention impl (DESIGN.md §15), resolved once
+        # here so a run can report what it ran: "auto" becomes the Pallas
+        # kernel on one TPU device (when the pool is stored in the compute
+        # dtype) and the gather_pages oracle elsewhere; "interpret" forces
+        # the kernel body through the Pallas interpreter (tests), "ref" pins
+        # the gather
+        self.attn_impl = resolve_paged_impl(
+            attn_impl, cfg.kv_cache_dtype or cfg.compute_dtype,
+            cfg.compute_dtype, partitioned=mesh is not None and mesh.size > 1)
         self.chunk_len = prefill_chunk
         self.max_pages = -(-max_len // page_size)       # per-slot table width
         # default pool: the dense engine's footprint (batch × max_len) plus
@@ -398,12 +404,16 @@ class PagedEngine:
         if mesh is not None:
             from .mesh import serve_rules
             self._rules = ShardingRules(mesh=mesh, rules=serve_rules())
+            # weights are replicated over the mesh once, here: left on the
+            # device they were made on, every jitted call would re-send them
+            # to the other devices
+            self.params = jax.device_put(params, NamedSharding(mesh, P()))
 
         def _decode(params, cache, tokens, page_table, update_mask):
             self._trace_counts["decode"] += 1
             return decode_step(cfg, params, cache, tokens, pages=page_table,
                                page_size=page_size, update_mask=update_mask,
-                               paged_impl=attn_impl)
+                               paged_impl=self.attn_impl)
 
         def _chunk(params, cache, tokens, pages_row, slot, start, valid_len):
             self._trace_counts["chunk_prefill"] += 1
@@ -659,7 +669,6 @@ class PagedEngine:
         self.ensure_batch()
         if self._pt_device is None:
             if self._rules is not None:
-                from jax.sharding import NamedSharding
                 spec = self._rules.spec_for(["slots", None],
                                             self.page_table.shape)
                 self._pt_device = jax.device_put(

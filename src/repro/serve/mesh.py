@@ -28,7 +28,6 @@ import dataclasses
 import jax
 from jax.sharding import Mesh, NamedSharding
 
-from repro.launch.mesh import compat_make_mesh
 from repro.parallel.sharding import DEFAULT_RULES, ShardingRules
 
 __all__ = ["MeshSpec", "build_serve_mesh", "serve_rules", "shard_paged_cache",
@@ -72,7 +71,8 @@ def build_serve_mesh(spec: MeshSpec) -> Mesh:
             f"{n_dev} visible — on CPU set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={spec.size} "
             f"before jax initialises")
-    return compat_make_mesh((spec.dp, spec.tp), ("data", "model"))
+    return jax.make_mesh((spec.dp, spec.tp), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def serve_rules() -> dict:

@@ -37,6 +37,12 @@ def run_variant(cfg, params, mesh, *, device_groups=1, batch=2, num_pages=26):
 
     eng = PagedEngine(cfg, params, batch=batch, max_len=64, page_size=8,
                       num_pages=num_pages, prefill_chunk=16, mesh=mesh)
+    if mesh is not None:
+        # weights live replicated on every device of the mesh, not on the
+        # one they were made on
+        for leaf in jax.tree.leaves(eng.params):
+            assert leaf.sharding.device_set == set(mesh.devices.flat)
+            assert leaf.sharding.is_fully_replicated
     sched = ServeScheduler(eng, sp=SamplingParams(), reserve="demand",
                            admit_watermark=1, device_groups=device_groups)
     rng = np.random.default_rng(7)

@@ -110,8 +110,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis.hlo import analyze_hlo, xla_cost_analysis
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((8,), ("d",))
+mesh = jax.make_mesh((8,), ("d",), axis_types=(jax.sharding.AxisType.Auto,))
 f = jax.jit(lambda a, b: a @ b,
             in_shardings=(NamedSharding(mesh, P(None, "d")),
                           NamedSharding(mesh, P("d", None))),

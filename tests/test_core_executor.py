@@ -93,6 +93,9 @@ def test_locality_aware_placement():
     _, rep = ex.run(g)
     seg1 = rep.segments[1]
     assert seg1.local_bytes > 0 and seg1.moved_bytes == 0
+    # the report records where each job ran: B followed A onto its worker
+    assert set(rep.job_devices) == {"A", "B"}
+    assert rep.job_devices["B"] == rep.job_devices["A"] == jax.devices()[0]
 
 
 def test_co_scheduling_same_function_jobs():

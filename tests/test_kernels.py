@@ -359,3 +359,41 @@ def test_paged_attention_all_trash_slot_is_finite():
         # kv_len 0 -> softmax collapses onto the self term: out == vt
         want = np.repeat(np.asarray(vt)[:, :, 0, :], 4 // 2, axis=1)
         np.testing.assert_allclose(out[:, 0], want, atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("impl,kv_dtype,partitioned,want", [
+    ("auto", jnp.float32, False, "ref"),           # CPU backend: gather
+    ("auto", jnp.float8_e4m3fn, False, "ref"),
+    ("interpret", jnp.float32, False, "interpret"),
+    ("interpret", jnp.float8_e4m3fn, False, ValueError),
+    ("kernel", jnp.float8_e4m3fn, False, ValueError),
+    ("auto", jnp.float32, True, "ref"),
+    ("kernel", jnp.float32, True, ValueError),
+    ("ref", jnp.float8_e4m3fn, True, "ref"),
+])
+def test_paged_impl_resolution_never_falls_back_silently(
+        impl, kv_dtype, partitioned, want):
+    """An explicit kernel request raises where the kernel cannot run (a pool
+    in another dtype than the query, a multi-device mesh); ``auto`` picks
+    the gather path there instead."""
+    from repro.kernels.paged_attention.ops import resolve_paged_impl
+    args = (impl, kv_dtype, jnp.float32)
+    if want is ValueError:
+        with pytest.raises(ValueError):
+            resolve_paged_impl(*args, partitioned=partitioned)
+    else:
+        assert resolve_paged_impl(*args, partitioned=partitioned) == want
+
+
+def test_paged_attention_f8_pool_rejects_explicit_kernel():
+    from repro.kernels.paged_attention.ops import paged_decode_attention
+    q, kp, vp, tbl, kv_len, kt, vt = _paged_case(
+        KEYS[6], 2, 4, 2, 32, 8, 3, (5, 9))
+    kp8, vp8 = (kp.at[0].set(0).astype(jnp.float8_e4m3fn),
+                vp.at[0].set(0).astype(jnp.float8_e4m3fn))
+    out = paged_decode_attention(q, kp8, vp8, tbl, kv_len, kt, vt,
+                                 impl="auto")
+    assert np.isfinite(np.asarray(out)).all()
+    with pytest.raises(ValueError, match="float8"):
+        paged_decode_attention(q, kp8, vp8, tbl, kv_len, kt, vt,
+                               impl="interpret")

@@ -397,3 +397,20 @@ def test_paged_hypar_tracker_matches_direct(qwen):
     _, hypar = run(HyParRequestTracker(2, strategy="cost",
                                        flops_per_token=1e6))
     assert direct == hypar
+
+
+def test_paged_engine_reports_resolved_attention_impl(qwen):
+    """The engine resolves its decode attention once, from the backend and
+    the pool dtype, and an explicit kernel request it cannot honour raises
+    at construction instead of serving on the gather path."""
+    cfg, params = qwen
+    eng = PagedEngine(cfg, params, batch=2, max_len=32, page_size=8,
+                      prefill_chunk=16)
+    assert eng.attn_impl == "ref"               # CPU backend: gather
+    eng = PagedEngine(cfg, params, batch=2, max_len=32, page_size=8,
+                      prefill_chunk=16, attn_impl="interpret")
+    assert eng.attn_impl == "interpret"
+    f8 = dataclasses.replace(cfg, kv_cache_dtype="float8_e4m3fn")
+    with pytest.raises(ValueError, match="float8"):
+        PagedEngine(f8, params, batch=2, max_len=32, page_size=8,
+                    prefill_chunk=16, attn_impl="interpret")

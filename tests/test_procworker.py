@@ -114,3 +114,18 @@ def test_store_survives_for_inspection_after_close(tmp_path):
         assert s.counts() == {"done": s.n_done()}
     finally:
         s.close()
+
+
+def test_worker_processes_are_pinned_to_the_cpu(tmp_path, monkeypatch):
+    """Spawned workers start with JAX_PLATFORMS=cpu whatever the master
+    has, so a worker table that imports jax never touches the accelerator
+    the master holds; the master's own environment is left as it was."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    with _make_executor(tmp_path / "jobs.sqlite") as ex:
+        ex.run(procdemo.build_graph(**SHAPE))
+        assert ex.procs
+        for ph in ex.procs.values():
+            with open(f"/proc/{ph.process.pid}/environ", "rb") as f:
+                env = f.read().split(b"\0")
+            assert b"JAX_PLATFORMS=cpu" in env
+    assert os.environ["JAX_PLATFORMS"] == "tpu,cpu"

@@ -118,8 +118,8 @@ SUB = textwrap.dedent("""
         s_ref, m_ref = step0(s_ref, b)
 
     # 4x2 mesh (EP over model for 4 experts? model=2 divides 4: EP engaged)
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = ShardingRules(mesh=mesh, rules=dict(DEFAULT_RULES))
     with use_rules(mesh, rules.rules):
         state_struct = jax.eval_shape(
@@ -159,8 +159,8 @@ SPMD_EXEC = textwrap.dedent("""
     def square(c):
         return c * c
 
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     g = JobGraph()
     g.add_segment([Job("J1", 1, 0), Job("J2", 1, 0)])
     g.bind_input("J1", np.arange(16, dtype=np.float32).reshape(16, 1), n_chunks=16)

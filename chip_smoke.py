@@ -177,7 +177,7 @@ def phase_jacobi(seed: int) -> None:
               f"wall={r.seconds:.4f} s")
         check(r.iters == JACOBI_ITERS, f"{name} ran {r.iters} iterations")
         check(err <= JACOBI_RTOL, f"{name} differs from numpy by {err:.3e}")
-    print(f"jacobi_hypar report: {hyp.extra['report']} "
+    print(f"jacobi_hypar report: {hyp.extra['report'].summary()} "
           f"moved_bytes={hyp.extra['moved_bytes']} "
           f"sweep devices={sweep_devices(hyp.extra['job_devices'])}")
 

@@ -38,6 +38,7 @@ import numpy as np
 from repro.core import (ChunkedData, ChunkRef, DataChunk, FunctionRegistry,
                         Job, JobGraph, LocalExecutor, IterativeSpec,
                         SpmdExecutor, VirtualCluster)
+from repro.core.spans import span
 from repro.kernels.jacobi_sweep.ops import jacobi_sweep_residual
 from repro.kernels.runtime import on_tpu
 from repro.kernels.tuning import calibrated_cost_params, get_tuner
@@ -110,6 +111,30 @@ def jacobi_tailored(A, b, *, iters: int = 500, tol: float = 0.0,
 def jacobi_hypar(A, b, *, iters: int = 500, tol: float = 0.0,
                  n_chunks: int = 4, cluster: VirtualCluster | None = None,
                  mode: str = "sync", strategy: str = "greedy") -> JacobiResult:
+    with span("jacobi.setup"):
+        ex, graph, state = _hypar_graph(A, b, iters=iters, tol=tol,
+                                        n_chunks=n_chunks, cluster=cluster,
+                                        mode=mode, strategy=strategy)
+    t0 = time.perf_counter()
+    results, report = ex.run(graph)
+    dt = time.perf_counter() - t0
+    with span("jacobi.finish"):
+        k = state["iter"]
+        x = np.asarray(results[f"X{k}"].get_data_chunk(0).data)
+        # exact residual: outside ex.run's clock, inside the caller's
+        res = float(jnp.linalg.norm(b - A @ jnp.asarray(x)))
+        return JacobiResult(x, k + 1, res, dt,
+                            extra={"report": report, "mode": mode,
+                                   "moved_bytes": report.moved_bytes,
+                                   "job_devices": report.job_devices})
+
+
+def _hypar_graph(A, b, *, iters: int, tol: float, n_chunks: int,
+                 cluster: VirtualCluster | None, mode: str, strategy: str):
+    """``jacobi_hypar``'s per-call set-up: the registry, the iteration-0
+    graph with its bound row chunks, the executor, and warm user kernels.
+    Returns ``(executor, graph, state)``; ``state["iter"]`` is the last
+    iteration J3 enqueued."""
     n = b.shape[0]
     diag = jnp.diag(A)
     reg = FunctionRegistry()
@@ -261,17 +286,7 @@ def jacobi_hypar(A, b, *, iters: int = 500, tol: float = 0.0,
                 A_rows.get_data_chunk(i), b_c.get_data_chunk(i),
                 d_c.get_data_chunk(i)]))
     graph.add_dynamic = add_dynamic
-
-    t0 = time.perf_counter()
-    results, report = ex.run(graph)
-    dt = time.perf_counter() - t0
-    k = state["iter"]
-    x = np.asarray(results[f"X{k}"].get_data_chunk(0).data)
-    res = float(jnp.linalg.norm(b - A @ jnp.asarray(x)))  # exact, untimed
-    return JacobiResult(x, k + 1, res, dt,
-                        extra={"report": report.summary(), "mode": mode,
-                               "moved_bytes": report.moved_bytes,
-                               "job_devices": report.job_devices})
+    return ex, graph, state
 
 
 # ---------------------------------------------------------------------------

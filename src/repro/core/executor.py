@@ -52,6 +52,7 @@ from .job import ChunkedData, DataChunk, GraphValidationError, Job, JobGraph
 from .registry import ControlContext, FunctionKind, FunctionRegistry
 from .scheduler import (CostModelParams, MasterScheduler, Placement,
                         ResultStore, VirtualCluster, Worker)
+from .spans import self_time, span, spans_between
 
 __all__ = [
     "ExecutionReport",
@@ -80,15 +81,20 @@ class SegmentReport:
     memoised_jobs: list[str] = dataclasses.field(default_factory=list)
     # device each executed job ran on (job name -> worker device)
     job_devices: dict[str, Any] = dataclasses.field(default_factory=dict)
-    sim_makespan: float = 0.0
-    wall_time: float = 0.0
+
+
+#: the job model's host phases, in the order ``summary()`` prints them
+PHASES = ("hypar.run", "hypar.segment", "hypar.plan", "hypar.job",
+          "hypar.wait")
 
 
 @dataclasses.dataclass
 class ExecutionReport:
     segments: list[SegmentReport] = dataclasses.field(default_factory=list)
-    dynamic_jobs_added: int = 0
     mode: str = "sync"
+    # time.perf_counter() at the run's start and end (LocalExecutor): the
+    # stretch whose recorded spans summary() reads
+    host_span: tuple[float, float] | None = None
 
     @property
     def moved_bytes(self) -> int:
@@ -110,11 +116,29 @@ class ExecutionReport:
     def job_devices(self) -> dict[str, Any]:
         return {j: d for s in self.segments for j, d in s.job_devices.items()}
 
+    def host_phases(self) -> dict[str, float] | None:
+        """Host seconds of the run in each ``hypar.*`` phase, as self time
+        (a phase's spans less the spans nested in them), so the phases add
+        up to the run's wall time.  None where the run recorded no spans or
+        the span recorder has already dropped some of them."""
+        if self.host_span is None:
+            return None
+        spans = spans_between(*self.host_span)
+        if spans is None:
+            return None
+        out = dict.fromkeys(PHASES, 0.0)
+        for s, ns in self_time([s for s in spans if s.name in out]):
+            out[s.name] += ns / 1e9
+        return out
+
     def summary(self) -> str:
+        phases = self.host_phases()
+        host = ("n/a" if phases is None else " ".join(
+            f"{name[6:]}={t:.4f}s" for name, t in phases.items()))
         return (f"mode={self.mode} segments={len(self.segments)} "
                 f"moved={self.moved_bytes}B "
-                f"local={self.local_bytes}B dynamic={self.dynamic_jobs_added} "
-                f"recovered={len(self.recovered_jobs)}")
+                f"local={self.local_bytes}B "
+                f"recovered={len(self.recovered_jobs)} host: {host}")
 
 
 # ---------------------------------------------------------------------------
@@ -236,13 +260,19 @@ class LocalExecutor(BaseExecutor):
                     moved.append(c)
             inputs.append(ChunkedData(moved))
         if job.name in graph.bound_inputs:
+            # bound inputs are sent to the worker again for every job that
+            # reads them (the Jacobi sweeps' rows of A, each iteration)
             data = graph.bound_inputs[job.name]
             moved = []
             for c in data:
                 on_dev = (isinstance(c.data, jax.Array) and c.data.devices()
                           and c.data.devices().pop() == worker.device)
-                moved.append(c if on_dev
-                             else DataChunk(jax.device_put(c.data, worker.device)))
+                if on_dev:
+                    report.local_bytes += c.nbytes
+                    moved.append(c)
+                else:
+                    report.moved_bytes += c.nbytes
+                    moved.append(DataChunk(jax.device_put(c.data, worker.device)))
             inputs.insert(0, ChunkedData(moved))
         return inputs
 
@@ -261,7 +291,7 @@ class LocalExecutor(BaseExecutor):
     # -- execution ----------------------------------------------------------------
     def _execute_on(self, job: Job, worker: Worker, graph: JobGraph,
                     report: SegmentReport,
-                    ctx: ControlContext | None = None) -> tuple[ChunkedData, float]:
+                    ctx: ControlContext | None = None) -> ChunkedData:
         """Resolve inputs, run the registered function, record the result.
 
         The dispatch lock is held only around shared-state access (store
@@ -271,50 +301,53 @@ class LocalExecutor(BaseExecutor):
         and compiled-function dispatch.
         """
         rf = self.registry[job.fn]
-        with self._lock:
-            inputs = self._resolve_inputs(job, graph, report, worker)
-            report.job_devices[job.name] = worker.device
-        t0 = time.perf_counter()
-        if rf.kind == FunctionKind.CHUNKWISE:
-            if not inputs:
-                raise GraphValidationError(
-                    f"{job.name}: chunkwise function {job.fn!r} needs input chunks")
-            fn = self._jitted(job.fn)
-            zipped = list(zip(*[cd.arrays() for cd in inputs]))
-            out_chunks = [DataChunk(fn(*args)) for args in zipped]
-            out = ChunkedData(out_chunks)
-        elif rf.kind == FunctionKind.WHOLE:
-            out = rf.fn(*inputs)
-            if not isinstance(out, ChunkedData):
-                out = ChunkedData.from_arrays(
-                    out if isinstance(out, (list, tuple)) else [out])
-        elif rf.kind == FunctionKind.CONTROL:
+        with span("hypar.job", job.name):
             with self._lock:
-                if ctx is None:
-                    ctx = ControlContext(graph, job.segment)
-                host_inputs = [ChunkedData([DataChunk(np.asarray(c.data))
-                                            for c in cd]) for cd in inputs]
-                out = rf.fn(*host_inputs, ctx)
-                if out is None:
-                    out = ChunkedData([])
-                elif not isinstance(out, ChunkedData):
+                inputs = self._resolve_inputs(job, graph, report, worker)
+                report.job_devices[job.name] = worker.device
+            t0 = time.perf_counter()
+            if rf.kind == FunctionKind.CHUNKWISE:
+                if not inputs:
+                    raise GraphValidationError(
+                        f"{job.name}: chunkwise function {job.fn!r} needs input chunks")
+                fn = self._jitted(job.fn)
+                zipped = list(zip(*[cd.arrays() for cd in inputs]))
+                out_chunks = [DataChunk(fn(*args)) for args in zipped]
+                out = ChunkedData(out_chunks)
+            elif rf.kind == FunctionKind.WHOLE:
+                out = rf.fn(*inputs)
+                if not isinstance(out, ChunkedData):
                     out = ChunkedData.from_arrays(
                         out if isinstance(out, (list, tuple)) else [out])
-                for new_job, seg_idx in ctx.added:
-                    graph.add_dynamic(new_job, seg_idx, current=job.segment)
-        else:  # pragma: no cover
-            raise GraphValidationError(f"unknown kind {rf.kind}")
-        if self.block_per_job:
-            for c in out:
-                if isinstance(c.data, jax.Array):
-                    c.data.block_until_ready()
-        elapsed = time.perf_counter() - t0
-        with self._lock:
-            worker.jobs_done += 1
-            self.store.put(job, out, worker)
-            if self._master is not None:
-                self._master.observe(job.fn, elapsed)
-        return out, elapsed
+            elif rf.kind == FunctionKind.CONTROL:
+                with self._lock:
+                    if ctx is None:
+                        ctx = ControlContext(graph, job.segment)
+                    with span("hypar.wait"):
+                        host_inputs = [ChunkedData([DataChunk(np.asarray(c.data))
+                                                    for c in cd]) for cd in inputs]
+                    out = rf.fn(*host_inputs, ctx)
+                    if out is None:
+                        out = ChunkedData([])
+                    elif not isinstance(out, ChunkedData):
+                        out = ChunkedData.from_arrays(
+                            out if isinstance(out, (list, tuple)) else [out])
+                    for new_job, seg_idx in ctx.added:
+                        graph.add_dynamic(new_job, seg_idx, current=job.segment)
+            else:  # pragma: no cover
+                raise GraphValidationError(f"unknown kind {rf.kind}")
+            if self.block_per_job:
+                with span("hypar.wait"):
+                    for c in out:
+                        if isinstance(c.data, jax.Array):
+                            c.data.block_until_ready()
+            elapsed = time.perf_counter() - t0
+            with self._lock:
+                worker.jobs_done += 1
+                self.store.put(job, out, worker)
+                if self._master is not None:
+                    self._master.observe(job.fn, elapsed)
+            return out
 
     def _maybe_speculate(self, p: Placement, sreport: SegmentReport) -> Worker:
         """Straggler mitigation: speculatively duplicate on a faster worker
@@ -332,12 +365,13 @@ class LocalExecutor(BaseExecutor):
 
     def _segment_barrier(self, names: Iterable[str]) -> None:
         """The paper's segment barrier: wait for every job of the segment."""
-        for name in names:
-            rec = self.store.records.get(name)
-            if rec is not None and rec.data is not None:
-                for c in rec.data:
-                    if isinstance(c.data, jax.Array):
-                        c.data.block_until_ready()
+        with span("hypar.wait"):
+            for name in names:
+                rec = self.store.records.get(name)
+                if rec is not None and rec.data is not None:
+                    for c in rec.data:
+                        if isinstance(c.data, jax.Array):
+                            c.data.block_until_ready()
 
     def run(self, graph: JobGraph, *, release_consumed: bool = False
             ) -> tuple[dict, ExecutionReport]:
@@ -352,16 +386,19 @@ class LocalExecutor(BaseExecutor):
                                        strategy=self.strategy,
                                        cost_params=self.cost_params,
                                        observed_fn_times=self.observed_fn_times)
+        t0 = time.perf_counter()
         try:
-            if self.mode == "sync":
-                self._run_sync(graph, report, release_consumed)
-            elif self.mode == "pipelined":
-                self._run_pipelined(graph, report, release_consumed)
-            else:
-                self._run_dataflow(graph, report, release_consumed)
+            with span("hypar.run"):
+                if self.mode == "sync":
+                    self._run_sync(graph, report, release_consumed)
+                elif self.mode == "pipelined":
+                    self._run_pipelined(graph, report, release_consumed)
+                else:
+                    self._run_dataflow(graph, report, release_consumed)
         finally:
             self._shutdown_queues()
             self._master = None
+        report.host_span = (t0, time.perf_counter())
         results = {name: rec.data for name, rec in self.store.records.items()
                    if rec.data is not None}
         return results, report
@@ -372,37 +409,30 @@ class LocalExecutor(BaseExecutor):
         master = self._master
         seg_idx = 0
         while seg_idx < len(graph.segments):
-            segment = graph.segments[seg_idx]
-            sreport = SegmentReport(index=seg_idx, jobs=list(segment.names()))
-            t0 = time.perf_counter()
-            worker_time: dict[int, float] = {}
-            n_dynamic_before = graph.n_jobs()
-            executed: set[str] = set()
-            # fixpoint over same-segment dynamic additions: control jobs may
-            # add to the *current* segment, which needs a re-plan pass
-            pending = list(segment.jobs)
-            while pending:
-                placements = master.plan_segment(pending, self.store)
-                for p in placements:
-                    if p.co_scheduled_with:
-                        sreport.co_scheduled.append((p.job.name,) + p.co_scheduled_with)
-                    worker = self._maybe_speculate(p, sreport)
-                    ctx = ControlContext(graph, seg_idx)
-                    _, elapsed = self._execute_on(p.job, worker, graph, sreport, ctx)
-                    worker_time[worker.wid] = worker_time.get(worker.wid, 0.0) \
-                        + elapsed * worker.slowdown
-                    executed.add(p.job.name)
-                pending = [j for j in segment.jobs if j.name not in executed]
-            n_dynamic_after = graph.n_jobs()
-            report.dynamic_jobs_added += max(0, n_dynamic_after - n_dynamic_before)
-            if not self.block_per_job:
-                self._segment_barrier(executed)
-            sreport.jobs = list(segment.names())
-            sreport.sim_makespan = max(worker_time.values(), default=0.0)
-            sreport.wall_time = time.perf_counter() - t0
-            report.segments.append(sreport)
-            if release_consumed:
-                self._release_dead_results(graph, seg_idx)
+            with span("hypar.segment", seg_idx):
+                segment = graph.segments[seg_idx]
+                sreport = SegmentReport(index=seg_idx)
+                executed: set[str] = set()
+                # fixpoint over same-segment dynamic additions: control jobs
+                # may add to the *current* segment, which needs a re-plan pass
+                pending = list(segment.jobs)
+                while pending:
+                    placements = master.plan_segment(pending, self.store)
+                    for p in placements:
+                        if p.co_scheduled_with:
+                            sreport.co_scheduled.append(
+                                (p.job.name,) + p.co_scheduled_with)
+                        worker = self._maybe_speculate(p, sreport)
+                        ctx = ControlContext(graph, seg_idx)
+                        self._execute_on(p.job, worker, graph, sreport, ctx)
+                        executed.add(p.job.name)
+                    pending = [j for j in segment.jobs if j.name not in executed]
+                if not self.block_per_job:
+                    self._segment_barrier(executed)
+                sreport.jobs = list(segment.names())
+                report.segments.append(sreport)
+                if release_consumed:
+                    self._release_dead_results(graph, seg_idx)
             seg_idx += 1
 
     # -- mode: pipelined (per-worker queues, strict segment barrier) -----------
@@ -411,48 +441,37 @@ class LocalExecutor(BaseExecutor):
         master = self._master
         seg_idx = 0
         while seg_idx < len(graph.segments):
-            segment = graph.segments[seg_idx]
-            sreport = SegmentReport(index=seg_idx, jobs=list(segment.names()))
-            t0 = time.perf_counter()
-            worker_time: dict[int, float] = {}
-            n_dynamic_before = graph.n_jobs()
-            executed: set[str] = set()
-            pending = list(segment.jobs)
-            while pending:
-                placements = master.plan_segment(pending, self.store)
-                futures: dict[str, tuple[concurrent.futures.Future, Worker]] = {}
-                for p in placements:
-                    if p.co_scheduled_with:
-                        sreport.co_scheduled.append((p.job.name,) + p.co_scheduled_with)
-                    worker = self._maybe_speculate(p, sreport)
-                    executed.add(p.job.name)
-                    if self.registry[p.job.fn].kind == FunctionKind.CONTROL:
-                        # host job: all deps live in earlier (drained)
-                        # segments, so it runs immediately on the host thread
-                        # while device queues fill
-                        ctx = ControlContext(graph, seg_idx)
-                        _, elapsed = self._execute_on(p.job, worker, graph,
-                                                      sreport, ctx)
-                        worker_time[worker.wid] = worker_time.get(worker.wid, 0.0) \
-                            + elapsed * worker.slowdown
-                    else:
-                        fut = self._queue(worker.wid).submit(
-                            self._execute_on, p.job, worker, graph, sreport)
-                        futures[p.job.name] = (fut, worker)
-                for name, (fut, worker) in futures.items():
-                    _, elapsed = fut.result()  # re-raises worker exceptions
-                    worker_time[worker.wid] = worker_time.get(worker.wid, 0.0) \
-                        + elapsed * worker.slowdown
-                pending = [j for j in segment.jobs if j.name not in executed]
-            n_dynamic_after = graph.n_jobs()
-            report.dynamic_jobs_added += max(0, n_dynamic_after - n_dynamic_before)
-            self._segment_barrier(executed)
-            sreport.jobs = list(segment.names())
-            sreport.sim_makespan = max(worker_time.values(), default=0.0)
-            sreport.wall_time = time.perf_counter() - t0
-            report.segments.append(sreport)
-            if release_consumed:
-                self._release_dead_results(graph, seg_idx)
+            with span("hypar.segment", seg_idx):
+                segment = graph.segments[seg_idx]
+                sreport = SegmentReport(index=seg_idx)
+                executed: set[str] = set()
+                pending = list(segment.jobs)
+                while pending:
+                    placements = master.plan_segment(pending, self.store)
+                    futures: list[concurrent.futures.Future] = []
+                    for p in placements:
+                        if p.co_scheduled_with:
+                            sreport.co_scheduled.append(
+                                (p.job.name,) + p.co_scheduled_with)
+                        worker = self._maybe_speculate(p, sreport)
+                        executed.add(p.job.name)
+                        if self.registry[p.job.fn].kind == FunctionKind.CONTROL:
+                            # host job: all deps live in earlier (drained)
+                            # segments, so it runs immediately on the host
+                            # thread while device queues fill
+                            ctx = ControlContext(graph, seg_idx)
+                            self._execute_on(p.job, worker, graph, sreport, ctx)
+                        else:
+                            futures.append(self._queue(worker.wid).submit(
+                                self._execute_on, p.job, worker, graph, sreport))
+                    for fut in futures:
+                        fut.result()  # re-raises worker exceptions
+                    pending = [j for j in segment.jobs if j.name not in executed]
+                self._segment_barrier(executed)
+                sreport.jobs = list(segment.names())
+                report.segments.append(sreport)
+                if release_consumed:
+                    self._release_dead_results(graph, seg_idx)
             seg_idx += 1
 
     # -- mode: dataflow (relaxed barrier, FIRST_COMPLETED draining) ------------
@@ -465,34 +484,28 @@ class LocalExecutor(BaseExecutor):
         in flight — JAX chains the data dependency).  Control jobs run on
         the host as their inputs complete, orco-style: the driver drains
         whichever future finishes first rather than a whole segment.
+        Segments interleave here, so no ``hypar.segment`` span is recorded.
         """
         master = self._master
-        t_run0 = time.perf_counter()
         futures: dict[str, tuple[concurrent.futures.Future, Worker, int]] = {}
         done: set[str] = set()          # device jobs with completed dispatch
         host_done: set[str] = set()     # executed control jobs
         seg_reports: dict[int, SegmentReport] = {}
-        seg_t0: dict[int, float] = {}
-        worker_time: dict[int, dict[int, float]] = {}
 
         def sreport_for(seg: int) -> SegmentReport:
             if seg not in seg_reports:
                 seg_reports[seg] = SegmentReport(index=seg)
-                seg_t0[seg] = time.perf_counter()
             return seg_reports[seg]
 
         def harvest() -> None:
             for name, (fut, worker, seg) in list(futures.items()):
                 if name in done or not fut.done():
                     continue
-                _, elapsed = fut.result()
-                wt = worker_time.setdefault(seg, {})
-                wt[worker.wid] = wt.get(worker.wid, 0.0) + elapsed * worker.slowdown
+                fut.result()
                 with self._lock:
                     self._inflight[worker.wid] = max(
                         0, self._inflight.get(worker.wid, 0) - 1)
                 done.add(name)
-                sreport_for(seg).wall_time = time.perf_counter() - seg_t0[seg]
 
         while True:
             harvest()
@@ -542,12 +555,8 @@ class LocalExecutor(BaseExecutor):
                 worker = (self.cluster.alive_workers()
                           or [self.cluster.spawn_worker()])[0]
                 ctx = ControlContext(graph, job.segment)
-                _, elapsed = self._execute_on(job, worker, graph, sr, ctx)
-                report.dynamic_jobs_added += len(ctx.added)
-                wt = worker_time.setdefault(job.segment, {})
-                wt[worker.wid] = wt.get(worker.wid, 0.0) + elapsed * worker.slowdown
+                self._execute_on(job, worker, graph, sr, ctx)
                 host_done.add(job.name)
-                sr.wall_time = time.perf_counter() - seg_t0[job.segment]
 
         # final barrier: everything must be device-complete before results
         # are handed back
@@ -555,7 +564,6 @@ class LocalExecutor(BaseExecutor):
         for seg, sr in sorted(seg_reports.items()):
             sr.jobs = (graph.segments[seg].names()
                        if seg < len(graph.segments) else [])
-            sr.sim_makespan = max(worker_time.get(seg, {}).values(), default=0.0)
             report.segments.append(sr)
         if release_consumed:
             for seg in range(len(graph.segments)):
@@ -660,9 +668,7 @@ class SpmdExecutor(BaseExecutor):
     def run(self, graph: JobGraph) -> tuple[dict[str, Any], ExecutionReport]:
         report = ExecutionReport(mode="spmd")
         for seg_idx, segment in enumerate(graph.segments):
-            sreport = SegmentReport(index=seg_idx, jobs=list(segment.names()))
-            t0 = time.perf_counter()
-            n_dynamic_before = graph.n_jobs()
+            sreport = SegmentReport(index=seg_idx)
             # group same-function chunkwise jobs (worker co-scheduling,
             # generalised: ONE sharded call executes the whole group)
             groups: dict[Any, list[Job]] = {}
@@ -705,10 +711,7 @@ class SpmdExecutor(BaseExecutor):
                         graph.add_dynamic(new_job, tgt, current=seg_idx)
                 else:  # pragma: no cover
                     raise GraphValidationError(f"unsupported kind {rf.kind}")
-            report.dynamic_jobs_added += max(
-                0, graph.n_jobs() - n_dynamic_before)
             sreport.jobs = list(segment.names())
-            sreport.wall_time = time.perf_counter() - t0
             report.segments.append(sreport)
         return dict(self.results), report
 

@@ -272,7 +272,7 @@ class ProcessExecutor(LocalExecutor):
 
     def _execute_on(self, job: Job, worker: Worker, graph: JobGraph,
                     report: SegmentReport,
-                    ctx: ControlContext | None = None) -> tuple[ChunkedData, float]:
+                    ctx: ControlContext | None = None) -> ChunkedData:
         rf = self.registry[job.fn]
         if rf.kind == FunctionKind.CONTROL:
             # control jobs stay on the master host (paper §3.3)
@@ -290,7 +290,7 @@ class ProcessExecutor(LocalExecutor):
                 report.memoised_jobs.append(job.name)
                 worker.jobs_done += 1
                 self.store.put(job, out, worker)
-            return out, time.perf_counter() - t0
+            return out
         arrays = self._dispatch_with_retry(job, worker, key, rf.kind,
                                            chunk_lists, report)
         out = ChunkedData([DataChunk(a) for a in arrays])
@@ -301,7 +301,7 @@ class ProcessExecutor(LocalExecutor):
             self.store.put(job, out, worker)
             if self._master is not None:
                 self._master.observe(job.fn, elapsed)
-        return out, elapsed
+        return out
 
     def _live_worker(self, preferred: Worker, deadline: float) -> Worker | None:
         """The placed worker if its process is live, else the least-loaded

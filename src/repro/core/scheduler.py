@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from .job import ChunkedData, ChunkRef, GraphValidationError, Job, JobGraph
+from .spans import span
 
 __all__ = [
     "Worker",
@@ -334,55 +335,56 @@ class MasterScheduler:
         keyed on job identity beyond the per-function EWMA, which is
         exactly what SHOULD carry over to the re-placed incarnation.
         """
-        loads = dict(loads or {})
-        placements: list[Placement] = []
-        # deterministic order: jobs sorted by (fn, name) so same-fn jobs are
-        # adjacent for co-scheduling
-        order = sorted(segment_jobs, key=lambda j: (str(j.fn), j.name))
-        cohab: dict[int, list[Placement]] = {}   # wid -> placements sharing it
+        with span("hypar.plan"):
+            loads = dict(loads or {})
+            placements: list[Placement] = []
+            # deterministic order: jobs sorted by (fn, name) so same-fn jobs are
+            # adjacent for co-scheduling
+            order = sorted(segment_jobs, key=lambda j: (str(j.fn), j.name))
+            cohab: dict[int, list[Placement]] = {}   # wid -> placements sharing it
 
-        for job in order:
-            # input-less jobs (serving admissions, source jobs) skip the
-            # result-directory walk entirely — on a hot admission path this
-            # is one dict scan per job per wave
-            by_loc = (self._input_bytes_by_location(job, store)
-                      if job.inputs else {})
-            total_in = sum(by_loc.values())
+            for job in order:
+                # input-less jobs (serving admissions, source jobs) skip the
+                # result-directory walk entirely — on a hot admission path this
+                # is one dict scan per job per wave
+                by_loc = (self._input_bytes_by_location(job, store)
+                          if job.inputs else {})
+                total_in = sum(by_loc.values())
 
-            # try co-scheduling with an already-placed same-fn job
-            placed = None
-            want = job.n_threads if job.n_threads > 0 else self.cluster.cores_per_worker
-            for wid, plist in cohab.items():
-                w = self.cluster.workers[wid]
-                if not w.alive:
-                    continue
-                used = sum(p.n_sequences for p in plist)
-                if (all(p.job.fn == job.fn for p in plist)
-                        and used + want <= w.cores):
-                    placed = Placement(job=job, worker=w, n_sequences=want,
-                                       co_scheduled_with=tuple(p.job.name for p in plist))
-                    break
+                # try co-scheduling with an already-placed same-fn job
+                placed = None
+                want = job.n_threads if job.n_threads > 0 else self.cluster.cores_per_worker
+                for wid, plist in cohab.items():
+                    w = self.cluster.workers[wid]
+                    if not w.alive:
+                        continue
+                    used = sum(p.n_sequences for p in plist)
+                    if (all(p.job.fn == job.fn for p in plist)
+                            and used + want <= w.cores):
+                        placed = Placement(job=job, worker=w, n_sequences=want,
+                                           co_scheduled_with=tuple(p.job.name for p in plist))
+                        break
 
-            if placed is None:
-                if self.strategy == "cost":
-                    w, est = self._choose_worker_cost(job, by_loc, total_in, loads)
-                else:
-                    w, est = self._choose_worker_greedy(job, by_loc, loads), 0.0
-                n_seq = min(want, w.cores) if want > 0 else w.cores
-                placed = Placement(job=job, worker=w, n_sequences=max(n_seq, 1),
-                                   est_cost_s=est)
+                if placed is None:
+                    if self.strategy == "cost":
+                        w, est = self._choose_worker_cost(job, by_loc, total_in, loads)
+                    else:
+                        w, est = self._choose_worker_greedy(job, by_loc, loads), 0.0
+                    n_seq = min(want, w.cores) if want > 0 else w.cores
+                    placed = Placement(job=job, worker=w, n_sequences=max(n_seq, 1),
+                                       est_cost_s=est)
 
-            local = by_loc.get(placed.worker.wid, 0)
-            placed.local_bytes = local
-            placed.moved_bytes = total_in - local
-            loads[placed.worker.wid] = loads.get(placed.worker.wid, 0) + 1
-            cohab.setdefault(placed.worker.wid, []).append(placed)
-            placements.append(placed)
+                local = by_loc.get(placed.worker.wid, 0)
+                placed.local_bytes = local
+                placed.moved_bytes = total_in - local
+                loads[placed.worker.wid] = loads.get(placed.worker.wid, 0) + 1
+                cohab.setdefault(placed.worker.wid, []).append(placed)
+                placements.append(placed)
 
-        # restore original job order for execution determinism
-        idx = {j.name: i for i, j in enumerate(segment_jobs)}
-        placements.sort(key=lambda p: idx[p.job.name])
-        return placements
+            # restore original job order for execution determinism
+            idx = {j.name: i for i, j in enumerate(segment_jobs)}
+            placements.sort(key=lambda p: idx[p.job.name])
+            return placements
 
     # -- worker choice ---------------------------------------------------------
     def _choose_worker_greedy(self, job: Job, by_loc: Mapping[int | None, int],

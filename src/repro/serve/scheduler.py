@@ -39,6 +39,7 @@ from typing import Any, Callable, Iterable, Sequence
 import jax
 import numpy as np
 
+from repro.core import spans
 from repro.core.job import ChunkedData, Job, JobGraph, ParallelSegment
 from repro.core.registry import ControlContext, FunctionKind, FunctionRegistry
 from repro.core.scheduler import (CostModelParams, MasterScheduler,
@@ -86,6 +87,12 @@ class Request:
     # with the ``expired`` outcome.
     ttft_deadline_s: float | None = None
     total_deadline_s: float | None = None
+    # scheduler-clock time the request got the slot its first token came
+    # from (set at admission; a resume keeps it)
+    admitted_s: float | None = None
+    # span-recorder clock (``spans.now()``) when it last entered the queue:
+    # accepted, or put back by a preemption or an eviction
+    queued_ns: int = 0
 
     @property
     def declared_new(self) -> int:
@@ -103,6 +110,9 @@ class RequestResult:
     arrival_s: float
     token_s: list[float]            # completion time of each token
     finish_s: float
+    # when the request got its slot: ``admitted_s - arrival_s`` is its
+    # wait in the queue, ``token_s[0] - admitted_s`` its prefill
+    admitted_s: float | None = None
 
     @property
     def n_generated(self) -> int:
@@ -185,6 +195,7 @@ class RequestQueue:
         if self.max_pending is not None and len(self._q) >= self.max_pending:
             self.shed_queue_full += 1
             return False
+        req.queued_ns = spans.now()
         self._q.append(req)
         self.n_submitted += 1
         return True
@@ -222,6 +233,8 @@ class SlotState:
     tokens: list[int] = dataclasses.field(default_factory=list)
     token_s: list[float] = dataclasses.field(default_factory=list)
     page_ids: list[int] = dataclasses.field(default_factory=list)
+    # span-recorder clock when the slot's request was assigned to it
+    assigned_ns: int = 0
     # chunked prefill in flight: remaining (start, bucket_len, valid) chunks
     pending_chunks: list[tuple[int, int, int]] = \
         dataclasses.field(default_factory=list)
@@ -1079,7 +1092,9 @@ class ServeScheduler:
     # -- slot lifecycle --------------------------------------------------------
     def _sample(self, logits) -> np.ndarray:
         self._key, sub = jax.random.split(self._key)
-        return np.asarray(Engine._sample(logits, sub, self.sp))
+        ids = Engine._sample(logits, sub, self.sp)
+        with spans.span("serve.wait"):
+            return np.asarray(ids)
 
     def _insert(self, req: Request, slot: int) -> None:
         S = len(req.tokens)
@@ -1090,6 +1105,8 @@ class ServeScheduler:
             self.engine.ensure_batch(enc_len=req.enc_embeds.shape[1])
         else:
             self.engine.ensure_batch()
+        self.slots[slot].assigned_ns = spans.now()
+        req.admitted_s = self.clock()
         logits = self.engine.insert(slot, padded, true_len=S,
                                     enc_embeds=req.enc_embeds)
         self._admit_seq += 1
@@ -1101,6 +1118,7 @@ class ServeScheduler:
         first token — time-to-first-token is measured here."""
         tok = int(self._sample(logits)[0])
         now = self.clock()
+        spans.interval("serve.prefill", st.assigned_ns, spans.now(), req.rid)
         st.request, st.pos, st.budget = req, len(req.tokens), req.max_new
         st.tokens, st.token_s = [tok], [now]
         st.next_token, st.finished = tok, False
@@ -1166,6 +1184,9 @@ class ServeScheduler:
         self._admit_seq += 1
         st.admit_seq = self._admit_seq
         sus = self._suspended.pop(req.rid, None) if self.demand else None
+        st.assigned_ns = spans.now()
+        if sus is None:
+            req.admitted_s = self.clock()
         st.resume = sus
         st.resume_base = len(sus.tokens) if sus else 0
         st.prefill_tokens = stream
@@ -1230,6 +1251,7 @@ class ServeScheduler:
         host-side bookkeeping; the next decode step feeds the last retained
         token as if the preemption never happened."""
         sus, req = st.resume, st.request
+        spans.interval("serve.prefill", st.assigned_ns, spans.now(), req.rid)
         st.resume = None
         st.tokens = list(sus.tokens)
         st.token_s = list(sus.token_s)
@@ -1313,8 +1335,10 @@ class ServeScheduler:
         all_free = [s for ss in free_by_gid.values() for s in ss]
         # wave entries: (req, group, reserved slot, pages, shared, stream)
         wave: list[tuple] = []
+        popped: dict[int, int] = {}      # rid -> when it left the queue
         while any(free_by_gid.values()) and len(self.queue):
             req = self.queue.pop()
+            popped[req.rid] = spans.now()
             if not self._fits(req):      # raw queue.submit bypassed admission
                 self.queue.shed_never_fits += 1
                 self._record_outcome(req.rid, "shed_queue",
@@ -1392,6 +1416,8 @@ class ServeScheduler:
         else:
             assign = {w[0].rid: w[2] for w in wave}
         for req, g, slot0, pages, shared, stream in wave:
+            spans.interval("serve.queue", req.queued_ns, popped[req.rid],
+                           req.rid)
             slot = assign[req.rid]
             if self.paged:
                 self._start_prefill(req, slot, pages, shared, stream)
@@ -1537,6 +1563,7 @@ class ServeScheduler:
             self.tracker.preempt(req)
         self._release_slot(st)
         self._clear_slot(st)
+        req.queued_ns = spans.now()
         self.queue.push_front(req)
 
     def _evict_request(self, st: SlotState, *,
@@ -1575,6 +1602,7 @@ class ServeScheduler:
                                 detail=f"restart budget "
                                        f"{self.max_restarts} exhausted")
                 return req.rid
+        req.queued_ns = spans.now()
         self.queue.push_front(req)
         return req.rid
 
@@ -1655,30 +1683,32 @@ class ServeScheduler:
                 or res.finish_s - req.arrival_s <= req.total_deadline_s)
 
     def _retire_finished(self) -> None:
-        now = self.clock()
-        for st in self.slots:
-            if st.request is None or not st.finished:
-                continue
-            req = st.request
-            res = RequestResult(rid=req.rid, prompt_len=len(req.tokens),
-                                tokens=list(st.tokens),
-                                arrival_s=req.arrival_s,
-                                token_s=list(st.token_s), finish_s=now)
-            self.results.append(res)
-            self._record_outcome(req.rid, "completed")
-            if self._deadlines_met(req, res):
-                self.goodput_tokens += res.n_generated
-            if self._last_retire_s is not None:
-                dt = now - self._last_retire_s
-                self._ewma_retire_s = (
-                    dt if self._ewma_retire_s == 0.0
-                    else 0.7 * self._ewma_retire_s + 0.3 * dt)
-            self._last_retire_s = now
-            if self.tracker is not None:
-                self.tracker.finish(req, st.slot, np.asarray(st.tokens))
-                self.tracker.retire(req)
-            self._release_slot(st)
-            self._clear_slot(st)
+        with spans.span("serve.retire"):
+            now = self.clock()
+            for st in self.slots:
+                if st.request is None or not st.finished:
+                    continue
+                req = st.request
+                res = RequestResult(rid=req.rid, prompt_len=len(req.tokens),
+                                    tokens=list(st.tokens),
+                                    arrival_s=req.arrival_s,
+                                    token_s=list(st.token_s), finish_s=now,
+                                    admitted_s=req.admitted_s)
+                self.results.append(res)
+                self._record_outcome(req.rid, "completed")
+                if self._deadlines_met(req, res):
+                    self.goodput_tokens += res.n_generated
+                if self._last_retire_s is not None:
+                    dt = now - self._last_retire_s
+                    self._ewma_retire_s = (
+                        dt if self._ewma_retire_s == 0.0
+                        else 0.7 * self._ewma_retire_s + 0.3 * dt)
+                self._last_retire_s = now
+                if self.tracker is not None:
+                    self.tracker.finish(req, st.slot, np.asarray(st.tokens))
+                    self.tracker.retire(req)
+                self._release_slot(st)
+                self._clear_slot(st)
 
     def _expire_running(self) -> None:
         """Retire in-flight requests whose TOTAL deadline has passed: the
@@ -1860,72 +1890,79 @@ class ServeScheduler:
         but the live batch keeps emitting tokens throughout instead of
         stalling for the whole prompt (the utilisation loss the paper's
         overlapping-segments design warns about)."""
-        self.step_calls += 1
-        if self.chaos is not None:
-            self.chaos.on_step(self)
-        self._probe_groups()
-        self._fill_free_slots()
-        for st in self.slots:
-            if st.prefilling:
-                t0 = self.clock()
-                self._advance_prefill(st)
-                self._watch_prefill(st, self.clock() - t0)
-        self._retire_finished()          # budget-1 requests end at prefill
-        if self.enforce_deadlines:
-            self._expire_running()
-        live = [s for s in self.slots
-                if s.request is not None and not s.prefilling]
-        if self.demand and live:
-            # reserve-on-demand: appends (or preemptions) BEFORE the decode
-            # write that would cross into an unowned page
-            live = self._ensure_decode_pages(live)
-        prefilling = [s for s in self.slots if s.prefilling]
-        if not live:
-            return bool(prefilling) or len(self.queue) > 0
-        t0 = self.clock()
-        tokens = np.zeros((self.engine.batch, 1), np.int32)
-        for st in live:
-            tokens[st.slot, 0] = st.next_token
-        if self.paged:
-            # freeze mid-prefill (and free) slots' SSM state: only slots
-            # decoding a real token may advance their per-slot buffers
-            mask = np.zeros((self.engine.batch,), bool)
+        with spans.span("serve.step"):
+            self.step_calls += 1
+            if self.chaos is not None:
+                self.chaos.on_step(self)
+            self._probe_groups()
+            with spans.span("serve.admit"):
+                self._fill_free_slots()
+            for st in self.slots:
+                if st.prefilling:
+                    t0 = self.clock()
+                    with spans.span("serve.prefill_chunk", st.request.rid):
+                        self._advance_prefill(st)
+                    self._watch_prefill(st, self.clock() - t0)
+            self._retire_finished()          # budget-1 requests end at prefill
+            if self.enforce_deadlines:
+                self._expire_running()
+            live = [s for s in self.slots
+                    if s.request is not None and not s.prefilling]
+            if self.demand and live:
+                # reserve-on-demand: appends (or preemptions) BEFORE the decode
+                # write that would cross into an unowned page
+                with spans.span("serve.pages"):
+                    live = self._ensure_decode_pages(live)
+            prefilling = [s for s in self.slots if s.prefilling]
+            if not live:
+                return bool(prefilling) or len(self.queue) > 0
+            t0 = self.clock()
+            tokens = np.zeros((self.engine.batch, 1), np.int32)
             for st in live:
-                mask[st.slot] = True
-            ids = self._sample(self.engine.decode(tokens, live_mask=mask))
-        else:
-            ids = self._sample(self.engine.decode(tokens))
-        now = self.clock()
-        self.n_steps += 1
-        self.occupied_slot_steps += len(live) + len(prefilling)
-        self._ewma_step_s = (now - t0 if self._ewma_step_s == 0.0
-                             else 0.7 * self._ewma_step_s
-                             + 0.3 * (now - t0))
-        if self.tracker is not None:
-            self.tracker.observe(now - t0, len(live))
-        busy = {s.slot for s in live} | {s.slot for s in prefilling}
-        for g in self.groups:
-            n_busy = sum(1 for s in g.slot_ids if s in busy)
-            g.occupied_slot_steps += n_busy
-            g_live = sum(1 for s in live if s.slot in g.slot_ids)
-            if g_live:
-                g.observe((now - t0) / g_live)
-        for st in live:
-            tok = int(ids[st.slot])
-            st.tokens.append(tok)
-            st.token_s.append(now)
-            st.next_token = tok
-            st.pos += 1
-            st.budget -= 1
-            if st.budget <= 0 or (self.sp.stop_token >= 0
-                                  and tok == self.sp.stop_token):
-                st.finished = True
-        # watchdog AFTER token bookkeeping: an evicted slot's suspended
-        # record then includes this wave's token, so resume recomputes the
-        # exact state and the output still bit-matches
-        self._watch_decode(live, now - t0)
-        self._retire_finished()
-        return True
+                tokens[st.slot, 0] = st.next_token
+            if self.paged:
+                # freeze mid-prefill (and free) slots' SSM state: only slots
+                # decoding a real token may advance their per-slot buffers
+                mask = np.zeros((self.engine.batch,), bool)
+                for st in live:
+                    mask[st.slot] = True
+                with spans.span("serve.decode"):
+                    logits = self.engine.decode(tokens, live_mask=mask)
+            else:
+                with spans.span("serve.decode"):
+                    logits = self.engine.decode(tokens)
+            ids = self._sample(logits)
+            now = self.clock()
+            self.n_steps += 1
+            self.occupied_slot_steps += len(live) + len(prefilling)
+            self._ewma_step_s = (now - t0 if self._ewma_step_s == 0.0
+                                 else 0.7 * self._ewma_step_s
+                                 + 0.3 * (now - t0))
+            if self.tracker is not None:
+                self.tracker.observe(now - t0, len(live))
+            busy = {s.slot for s in live} | {s.slot for s in prefilling}
+            for g in self.groups:
+                n_busy = sum(1 for s in g.slot_ids if s in busy)
+                g.occupied_slot_steps += n_busy
+                g_live = sum(1 for s in live if s.slot in g.slot_ids)
+                if g_live:
+                    g.observe((now - t0) / g_live)
+            for st in live:
+                tok = int(ids[st.slot])
+                st.tokens.append(tok)
+                st.token_s.append(now)
+                st.next_token = tok
+                st.pos += 1
+                st.budget -= 1
+                if st.budget <= 0 or (self.sp.stop_token >= 0
+                                      and tok == self.sp.stop_token):
+                    st.finished = True
+            # watchdog AFTER token bookkeeping: an evicted slot's suspended
+            # record then includes this wave's token, so resume recomputes the
+            # exact state and the output still bit-matches
+            self._watch_decode(live, now - t0)
+            self._retire_finished()
+            return True
 
     def run(self, requests: Iterable[Request] | None = None,
             ) -> list[RequestResult]:

@@ -3,25 +3,32 @@
 vLLM-style paged attention (DESIGN.md §15): one query token per slot
 attends over that slot's KV pages *in place* in the shared page pool.  The
 per-slot page table and per-slot cache lengths ride in as scalar-prefetch
-operands (``pltpu.PrefetchScalarGridSpec``), so the K/V BlockSpec index
-maps resolve ``logical page j of slot b -> physical pool page
-table[b, j]`` at DMA-issue time — no dense per-slot gather is ever
-materialised (the ~4%/step copy `gather_pages` pays).
+operands (``pltpu.PrefetchScalarGridSpec``, the table first); the pools
+stay in HBM (``memory_space=pl.ANY``) and the kernel copies the pages it
+needs itself, so no dense per-slot gather is ever materialised.
 
-Grid: (batch, kv_head_blocks, logical_pages) with the page axis innermost
-and sequential; the running max / denominator / accumulator live in VMEM
-scratch across page steps (the standard TPU flash-decode schedule).  GQA is
-native to the layout: q arrives grouped as (B, KV, G, D) so each kv-head
-block reads exactly its own pool heads.
+Grid: one step per slot.  Inside it a loop runs over blocks of
+``pages_per_block`` pages, from the slot's first live page (0, or the page
+holding the sliding window's start) to its last (``ceil(kv_len /
+page_size)``): the work follows each slot's live pages, not the table's
+width, and a slot with ``kv_len == 0`` costs one empty step and no copy.
+Each page of the ``(P, KV, page_size, D)`` pool holds both kv heads
+contiguously, so one DMA per page brings both heads of K (and one of V)
+into a VMEM block buffer ``(KV, pages_per_block * page_size, D)``.  The
+buffers are doubled: the next block, of this slot or of the next slot with
+live pages, is started before the current one is computed.  Each block is
+one f32 online-softmax update per kv head: q ``(g_pad, D)`` against the
+block's keys.  GQA is native to the layout: q arrives grouped as
+``(B, KV, g_pad, D)``.
 
 Masking contract (shared with ``models.attention.gather_pages``): physical
 page 0 is the reserved trash page — decode writes of free/mid-prefill slots
-land there, so its contents are arbitrary.  Blocks whose resolved page id
-is 0 read K/V as ZEROS (not NEG_INF): positions inside ``kv_len`` still
-contribute exp(0 - m) to the denominator, exactly like the zero-filled
-rows the gather path produces, so kernel and gather outputs match bit-for-
-token even on slots whose tables point at the trash page.  Positions at or
-past ``kv_len`` (and outside the sliding window) are masked to NEG_INF.
+land there, so its contents are arbitrary.  It is never copied: its rows in
+the block buffer are copied from a zero page instead (ZEROS, not NEG_INF),
+so positions inside ``kv_len`` still contribute exp(0 - m) to the
+denominator, exactly like the zero-filled rows the gather path produces.  Positions at or past
+``kv_len`` (and outside the sliding window) are masked to NEG_INF; the
+buffers are zeroed once per call, so what such rows hold is always finite.
 
 The kernel emits the *unnormalised* accumulator plus the running (m, l)
 statistics; the ops wrapper LSE-merges the current token's own K/V (the
@@ -41,70 +48,149 @@ NEG_INF = -2.3819763e38
 LANE = 128   # the (m, l) outputs broadcast over a full lane dim
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
-                  acc_ref, m_ref, l_ref, m_scr, l_scr, acc_scr, *,
-                  window: int | None, page_size: int, g_pad: int,
-                  n_pages: int):
+def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
+                  acc_ref, m_ref, l_ref, k_buf, v_buf, zero_page, sem,
+                  state, *,
+                  window: int | None, page_size: int, ppb: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    n_slots = pl.num_programs(0)
+    n_pages = tbl_ref.shape[1]
+    KV, g_pad, D = q_ref.shape[1:]
+    ps = page_size
 
-    @pl.when(j == 0)
+    def live_pages(slot):
+        """[first, last) logical pages of ``slot`` holding a valid key."""
+        kv_len = len_ref[slot]
+        last = jnp.minimum((kv_len + ps - 1) // ps, n_pages)
+        if window is None:
+            return jnp.int32(0), last
+        first = jnp.maximum(kv_len + 1 - window, 0) // ps
+        return jnp.minimum(first, last), last
+
+    def n_blocks(first, last):
+        return (last - first + ppb - 1) // ppb
+
+    def block_pages(first, last, block):
+        return jnp.minimum(ppb, last - first - block * ppb)
+
+    def start_block(slot, block, buf):
+        """Copy one block's live pages into buffer ``buf``: both kv heads
+        of a page in one DMA per pool; a trash page's rows are copied
+        from the zero page instead."""
+        first, last = live_pages(slot)
+        base = first + block * ppb
+
+        def page(j, _):
+            pid = tbl_ref[slot, base + j]
+            rows = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            for pool, vmem in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                @pl.when(pid != 0)
+                def _copy():
+                    pltpu.make_async_copy(pool.at[pid], vmem.at[buf, :, rows],
+                                          sem.at[buf]).start()
+
+                @pl.when(pid == 0)
+                def _trash():
+                    pltpu.make_async_copy(zero_page, vmem.at[buf, :, rows],
+                                          sem.at[buf]).start()
+
+        jax.lax.fori_loop(0, block_pages(first, last, block), page, None)
+
+    def wait_block(block, buf):
+        """Wait for the copies of this slot's block in ``buf``: every copy
+        moves one page, so one page-sized wait each."""
+        def page(j, _):
+            for vmem in (k_buf, v_buf):
+                pltpu.make_async_copy(zero_page, vmem.at[buf, :, pl.ds(0, ps)],
+                                      sem.at[buf]).wait()
+
+        jax.lax.fori_loop(0, block_pages(first, last, block), page, None)
+
+    # state[0]: buffer the next block lands in; state[1]: 1 when this
+    # slot's first block was already started by an earlier slot
+    @pl.when(b == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        zero_page[...] = jnp.zeros_like(zero_page)
+        state[0] = 0
+        state[1] = 0
 
     kv_len = len_ref[b]
-    pid = tbl_ref[b, j]
+    first, last = live_pages(b)
+    nb = n_blocks(first, last)
 
-    # skip pages that cannot contain a valid position: entirely at/past the
-    # slot's length, or (sliding window) entirely before the window start
-    run = j * page_size < kv_len
-    if window is not None:
-        run = jnp.logical_and(
-            run, (j + 1) * page_size - 1 >= kv_len + 1 - window)
+    @pl.when(jnp.logical_and(nb > 0, state[1] == 0))
+    def _first_block():
+        start_block(b, 0, state[0])
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)                 # (hb, g_pad, D)
-        k = k_ref[0].astype(jnp.float32)                 # (hb, ps, D)
-        v = v_ref[0].astype(jnp.float32)
-        # trash page: read as zeros — see the masking contract above
-        k = jnp.where(pid == 0, 0.0, k)
-        v = jnp.where(pid == 0, 0.0, v)
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32)
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (g_pad, page_size), 1)
+    def empty(s):
+        f, l = live_pages(jnp.minimum(s, n_slots - 1))
+        return jnp.logical_and(s < n_slots, n_blocks(f, l) == 0)
+
+    # the next slot with live pages: its first block follows this slot's
+    # last (an empty slot looks for none)
+    next_slot = jax.lax.while_loop(empty, lambda s: s + 1,
+                                   jnp.where(nb > 0, b + 1, n_slots))
+
+    @pl.when(nb > 0)
+    def _chain():
+        state[1] = (next_slot < n_slots).astype(jnp.int32)
+
+    def body(i, carry):
+        cur = state[0]
+        nxt = 1 - cur
+        last_block = i + 1 == nb
+        slot = jnp.where(last_block, next_slot, b)
+
+        @pl.when(slot < n_slots)
+        def _next_block():
+            start_block(slot, jnp.where(last_block, 0, i + 1), nxt)
+
+        wait_block(i, cur)
+        state[0] = nxt
+
+        pos = (first + i * ppb) * ps + jax.lax.broadcasted_iota(
+            jnp.int32, (g_pad, ppb * ps), 1)
         ok = pos < kv_len
         if window is not None:
             ok = jnp.logical_and(ok, pos >= kv_len + 1 - window)
-        s = jnp.where(ok[None], s, NEG_INF)              # (hb, g_pad, ps)
+        out = []
+        for h in range(KV):
+            m_prev, l_prev, acc_prev = carry[h]
+            k = k_buf[cur, h].astype(jnp.float32)            # (T, D)
+            v = v_buf[cur, h].astype(jnp.float32)
+            s = jax.lax.dot_general(qs[h], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(ok, s, NEG_INF)                    # (g_pad, T)
+            m = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m)
+            corr = jnp.exp(m_prev - m)
+            pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            out.append((m, l_prev * corr + jnp.sum(p, axis=1, keepdims=True),
+                        acc_prev * corr + pv))
+        return tuple(out)
 
-        m_prev = m_scr[...]                              # (hb, g_pad, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=2, keepdims=True)
-        m_scr[...] = m_new
-        pv = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * corr + pv
-
-    @pl.when(j == n_pages - 1)
-    def _emit():
-        acc_ref[0] = acc_scr[...]
-        m_ref[0] = jnp.broadcast_to(m_scr[...], m_ref.shape[1:])
-        l_ref[0] = jnp.broadcast_to(l_scr[...], l_ref.shape[1:])
+    qs = [q_ref[0, h].astype(jnp.float32) for h in range(KV)]  # (g_pad, D)
+    init = ((jnp.full((g_pad, 1), NEG_INF, jnp.float32),
+             jnp.zeros((g_pad, 1), jnp.float32),
+             jnp.zeros((g_pad, D), jnp.float32)),) * KV
+    for h, (m, l, acc) in enumerate(jax.lax.fori_loop(0, nb, body, init)):
+        acc_ref[0, h] = acc
+        m_ref[0, h] = jnp.broadcast_to(m, (g_pad, LANE))
+        l_ref[0, h] = jnp.broadcast_to(l, (g_pad, LANE))
 
 
 def paged_attention_kernel(q, k_pool, v_pool, pages, kv_len, *,
-                           window: int | None = None, head_block: int = 1,
+                           pages_per_block: int,
+                           window: int | None = None,
                            interpret: bool = False):
     """q: (B, KV, g_pad, D) pre-scaled grouped queries; k/v_pool:
     (P, KV, page_size, D) shared pools; pages: (B, n_pages) int32 page
     table; kv_len: (B,) int32 valid lengths (OLD lengths — the current
-    token's self term is merged outside).
+    token's self term is merged outside); ``pages_per_block``: pages per
+    copy-and-compute block.
 
     Returns ``(acc, m, l)``: unnormalised f32 accumulator
     (B, KV, g_pad, D) and running max / denominator broadcast over a LANE
@@ -112,39 +198,30 @@ def paged_attention_kernel(q, k_pool, v_pool, pages, kv_len, *,
     """
     B, KV, g_pad, D = q.shape
     ps = k_pool.shape[2]
-    n_pages = pages.shape[1]
-    hb = head_block
-    assert KV % hb == 0, (KV, hb)
+    ppb = pages_per_block
 
-    kernel = functools.partial(
-        _paged_kernel, window=window, page_size=ps, g_pad=g_pad,
-        n_pages=n_pages)
-
+    kernel = functools.partial(_paged_kernel, window=window, page_size=ps,
+                               ppb=ppb)
+    per_slot = lambda b, tbl, lens: (b, 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV // hb, n_pages),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, hb, g_pad, D),
-                         lambda b, kb, j, tbl, lens: (b, kb, 0, 0)),
-            # logical page j of slot b lives in physical page tbl[b, j] —
-            # the index map IS the gather
-            pl.BlockSpec((1, hb, ps, D),
-                         lambda b, kb, j, tbl, lens: (tbl[b, j], kb, 0, 0)),
-            pl.BlockSpec((1, hb, ps, D),
-                         lambda b, kb, j, tbl, lens: (tbl[b, j], kb, 0, 0)),
+            pl.BlockSpec((1, KV, g_pad, D), per_slot),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, hb, g_pad, D),
-                         lambda b, kb, j, tbl, lens: (b, kb, 0, 0)),
-            pl.BlockSpec((1, hb, g_pad, LANE),
-                         lambda b, kb, j, tbl, lens: (b, kb, 0, 0)),
-            pl.BlockSpec((1, hb, g_pad, LANE),
-                         lambda b, kb, j, tbl, lens: (b, kb, 0, 0)),
+            pl.BlockSpec((1, KV, g_pad, D), per_slot),
+            pl.BlockSpec((1, KV, g_pad, LANE), per_slot),
+            pl.BlockSpec((1, KV, g_pad, LANE), per_slot),
         ],
         scratch_shapes=[
-            pltpu.VMEM((hb, g_pad, 1), jnp.float32),   # running max
-            pltpu.VMEM((hb, g_pad, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((hb, g_pad, D), jnp.float32),   # output accumulator
+            pltpu.VMEM((2, KV, ppb * ps, D), k_pool.dtype),   # K blocks
+            pltpu.VMEM((2, KV, ppb * ps, D), v_pool.dtype),   # V blocks
+            pltpu.VMEM((KV, ps, D), k_pool.dtype),            # trash reads
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     return pl.pallas_call(
@@ -155,5 +232,9 @@ def paged_attention_kernel(q, k_pool, v_pool, pages, kv_len, *,
             jax.ShapeDtypeStruct((B, KV, g_pad, LANE), jnp.float32),
             jax.ShapeDtypeStruct((B, KV, g_pad, LANE), jnp.float32),
         ],
+        # slots share the block buffers and the copies in flight, so the
+        # grid runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(pages, kv_len, q, k_pool, v_pool)

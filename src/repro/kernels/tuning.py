@@ -57,10 +57,11 @@ DEFAULT_CANDIDATES: dict[str, list[dict[str, int]]] = {
     "rmsnorm": [{"row_block": r} for r in (64, 128, 256, 512)],
     "flash_attention": [{"q_block": q, "kv_block": k}
                         for q in (128, 256, 512) for k in (128, 256, 512)],
-    # the SSD kernel tiles by its (chunk, head) grid — nothing to search yet,
-    # but timing it populates the cost-model bridge
+    # the SSD kernel tiles by its (chunk, head) grid and the paged kernel's
+    # block follows from its shapes — nothing to search, but timing them
+    # populates the cost-model bridge
     "ssd_scan": [{}],
-    "paged_attention": [{"head_block": h} for h in (1, 2)],
+    "paged_attention": [{}],
 }
 
 _ENV_CACHE = "REPRO_TUNE_CACHE"

@@ -270,8 +270,9 @@ def _paged_case(key, B, H, KV, D, page_size, n_slot_pages, kv_lens, *,
     lengths, trailing trash-page entries, optionally slots SHARING physical
     pages (the prefix-cache / COW refcount>1 read case — DESIGN.md §11) and
     mid-prefill slots whose last ``trash_tail`` in-range logical pages still
-    point at trash page 0.  Page 0 is filled with NaN: the masking contract
-    says its contents must never reach an output."""
+    point at trash page 0 (a tuple instead names the in-range logical pages
+    that do).  Page 0 is filled with NaN: the masking contract says its
+    contents must never reach an output."""
     ks = jax.random.split(key, 5)
     n_pool = 1 + B * n_slot_pages
     k_pool = jax.random.normal(ks[0], (n_pool, KV, page_size, D), jnp.float32)
@@ -288,7 +289,9 @@ def _paged_case(key, B, H, KV, D, page_size, n_slot_pages, kv_lens, *,
             else:
                 table[b, i] = nxt
                 nxt += 1
-        for i in range(max(need - trash_tail, 0), need):
+        trash = (range(max(need - trash_tail, 0), need)
+                 if isinstance(trash_tail, int) else trash_tail)
+        for i in trash:
             table[b, i] = 0                    # mid-prefill: unwritten page
     q = jax.random.normal(ks[2], (B, 1, H, D), jnp.float32)
     kt = jax.random.normal(ks[3], (B, KV, 1, D), jnp.float32)
@@ -305,23 +308,25 @@ PA_CASES = [
     (3, 2, 2, 64, 16, 4, (33, 33, 50), None, True, 0),   # shared/COW pages
     (2, 4, 2, 32, 8, 5, (37, 21), None, False, 1),       # mid-prefill trash
     (2, 4, 1, 64, 16, 2, (9, 25), 7, True, 0),
+    # the kernel's page blocks (run at 1 and 4 pages per block):
+    (2, 4, 2, 32, 8, 11, (83, 45), None, False, 0),      # ragged last block
+    (3, 4, 2, 32, 8, 8, (32, 64, 0), None, False, 0),    # on block bounds
+    (2, 4, 2, 32, 8, 6, (48, 41), None, False, 0),       # every table page
+    (3, 4, 2, 32, 8, 4, (0, 0, 0), None, False, 0),      # no live slot
+    (2, 4, 2, 32, 8, 6, (44, 36), None, False, (1, 2)),  # trash mid-block
 ]
-
-
 @pytest.mark.parametrize("case", PA_CASES)
-@pytest.mark.parametrize("head_block", [1, 2])
-def test_paged_attention_kernel_matches_ref(case, head_block):
+@pytest.mark.parametrize("pages_per_block", [1, 4])
+def test_paged_attention_kernel_matches_ref(case, pages_per_block):
     from repro.kernels.paged_attention.ops import paged_decode_attention
     B, H, KV, D, ps, n, lens, window, share, trash = case
-    if head_block > KV:
-        pytest.skip("head_block exceeds KV heads")
     q, kp, vp, tbl, kv_len, kt, vt = _paged_case(
         KEYS[3], B, H, KV, D, ps, n, lens, share=share, trash_tail=trash)
     ref = paged_decode_attention(q, kp, vp, tbl, kv_len, kt, vt,
                                  window=window, impl="ref")
     out = paged_decode_attention(q, kp, vp, tbl, kv_len, kt, vt,
                                  window=window, impl="interpret",
-                                 head_block=head_block)
+                                 pages_per_block=pages_per_block)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-6, rtol=2e-6)

@@ -21,15 +21,16 @@ HBM at the chip's peak; nothing shorter can have read it).
 
 In a control run (``cell.control``, set only by ``bench/tools/controls.py``)
 the ``x`` compared is the control's: the plain iteration on the device with
-``A`` stored in bfloat16 (``reference.jacobi.control_solve``)."""
+``A`` stored in bfloat16 (``control_solve`` of the configuration's reference,
+``bench/reference/jacobi.py``)."""
 from __future__ import annotations
 
 import gc
 import time
 
+from bench import reference
 from bench.lib import device
 from bench.lib.cell import log
-from bench.reference import jacobi as ref
 
 
 def sweep_devices(job_devices: dict) -> set:
@@ -83,6 +84,7 @@ def run(cell, jax) -> None:
     from repro.core import VirtualCluster
 
     cfg, mix = cell.config, cell.mix
+    ref = reference.find(cfg["system"])
     n, iters, tol = int(cfg["n"]), int(mix["iterations"]), float(mix["tol"])
     n_chunks = int(cfg["n_chunks"])
     devs = cell.devices

@@ -72,7 +72,7 @@ def run(cell, jax) -> None:
     cell.failed = srv.n_failed(list(subs))
     cell.data.update({"records": records, "open": t_open, "close": t_close,
                       "recorder": rec, "cfg": srv.cfg,
-                      "n_params": srv.n_params,
+                      "body": srv.body,
                       "page_size": srv.engine.page_size,
                       "slots": srv.engine.batch,
                       "max_pages": srv.engine.max_pages,

@@ -1,13 +1,16 @@
 """What the serve drivers share: the system under test built from the
 configuration, its warm-up, the harness loop's bookkeeping, the recorder
-of calls into the engine, and the correctness check against the plain
-reference.
+of calls into the engine, the model's FLOPs and the paged kernel's bytes
+counted from the program's layer plan, and the correctness check against
+the plain reference of the configuration's ``system``
+(``bench/reference/<system>.py``, found by ``bench.reference.find``).
 
 The system under test is ``ServeScheduler`` over ``PagedEngine`` with
 ``HyParRequestTracker``, as ``launch/serve.py --trace --paged --engine
 hypar`` builds it; the harness drives it through ``submit`` and ``step``."""
 from __future__ import annotations
 
+import collections
 import dataclasses
 import gc
 import math
@@ -15,36 +18,38 @@ import time
 
 import numpy as np
 
+from bench import reference
 from bench.lib import device, traffic
 from bench.lib.cell import log
-from bench.reference import qwen2 as ref
 
-# published config.json key -> ModelConfig field
-PUBLISHED = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-             "num_attention_heads": "n_heads",
-             "num_key_value_heads": "n_kv_heads",
-             "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-             "rope_theta": "rope_theta",
-             "tie_word_embeddings": "tie_embeddings",
-             "max_position_embeddings": "max_seq"}
+# the sizes every decoder's published config.json states -> ModelConfig
+# field; what belongs to one system its reference checks (``check_config``)
+DECODER = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
+           "num_attention_heads": "n_heads",
+           "num_key_value_heads": "n_kv_heads", "vocab_size": "vocab_size",
+           "tie_word_embeddings": "tie_embeddings",
+           "max_position_embeddings": "max_seq"}
 
 
 def model_config(conf: dict):
     """The system's ``ModelConfig`` for this configuration: the repository's
-    own preset with the served dtype, checked against every published size."""
+    own preset with the served dtype, checked against each size of
+    ``DECODER`` the configuration states, then by the reference of its
+    ``system``, where that reference has a ``check_config``."""
     from repro.configs import get_config, get_smoke_config
     preset = (get_smoke_config if conf.get("program_size") == "smoke"
               else get_config)
     cfg = dataclasses.replace(preset(conf["program_arch"]),
                               param_dtype=conf["torch_dtype"],
                               compute_dtype=conf["torch_dtype"])
-    for key, field in PUBLISHED.items():
-        if getattr(cfg, field) != conf[key]:
+    for key, field in DECODER.items():
+        if key in conf and getattr(cfg, field) != conf[key]:
             raise ValueError(f"{conf['program_arch']}: {field}="
                              f"{getattr(cfg, field)!r} but the published "
                              f"{key} is {conf[key]!r}")
-    if not cfg.qkv_bias or cfg.hd * cfg.n_heads != conf["hidden_size"]:
-        raise ValueError("qwen2 needs q/k/v biases and hidden/heads head size")
+    ref = reference.find(conf["system"])
+    if hasattr(ref, "check_config"):
+        ref.check_config(cfg, conf)
     return cfg
 
 
@@ -91,6 +96,7 @@ class Server:
         srv = conf["serving"]
         self.jax = jax
         self.conf = conf
+        self.ref = reference.find(conf["system"])
         self.cfg = model_config(conf)
         self.slots = int(mix["slots"])
         # the page pool is allocated before the weights: the program builds
@@ -105,12 +111,13 @@ class Server:
                           attn_impl=srv["paged_attention"])
         eng.ensure_batch()
         jax.block_until_ready(eng.cache)
-        self.params = ref.program_params(
+        self.params = self.ref.program_params(
             jax, conf, cell.seed, padded_vocab=self.cfg.padded_vocab,
             dtype=conf["torch_dtype"])
         jax.block_until_ready(self.params)
         eng.params = self.params
         self.n_params = sum(int(x.size) for x in jax.tree.leaves(self.params))
+        self.body = token_params(self.cfg, self.params)
         tracker = HyParRequestTracker(self.slots, strategy=srv["strategy"],
                                       flops_per_token=2.0 * self.n_params)
         self.sched = ServeScheduler(eng, sp=SamplingParams(), tracker=tracker,
@@ -186,40 +193,133 @@ class Server:
 # ---------------------------------------------------------------------------
 
 
-def flops(cfg, n_params: int, chunks, decodes) -> float:
-    """Model FLOPs of the calls: 2 x (parameters outside the embedding) per
-    token processed, 2 x vocab x d per logit row computed (one per chunk,
-    one per decoded token), and attention at each token's context
-    (4 x layers x heads x head_dim per attended position)."""
+# block kinds of the program's layer plan whose mixer is attention -> the
+# window its keys lie in (None or 0: every key before the token); the
+# other kinds' mixers (``ssm``) are counted by their parameters only
+ATTENTION = {"dense": lambda cfg: cfg.sliding_window,
+             "moe": lambda cfg: cfg.sliding_window,
+             "local": lambda cfg: cfg.local_window,
+             "global": lambda cfg: None,
+             "shared": lambda cfg: None}
+OTHER_MIXERS = ("ssm",)
+
+
+def plan_kinds(cfg) -> list[str]:
+    """The block kind of each layer a token passes through, in order: the
+    program's layer plan (``repro.models.transformer.layer_plan``)
+    unrolled, a shared block once per use."""
+    from repro.models.transformer import layer_plan
+    plan = layer_plan(cfg)
+    kinds = list(plan.pattern) * plan.n_groups + list(plan.tail)
+    unknown = set(kinds) - set(ATTENTION) - set(OTHER_MIXERS)
+    if unknown:
+        raise ValueError(f"{cfg.name}: no cost rule for layer kinds "
+                         f"{sorted(unknown)}")
+    return kinds
+
+
+def attention_windows(cfg) -> dict:
+    """{window (None: every key): attention layers a token passes through
+    with that window}."""
+    return dict(collections.Counter(ATTENTION[k](cfg) or None
+                                    for k in plan_kinds(cfg)
+                                    if k in ATTENTION))
+
+
+def token_params(cfg, params) -> int:
+    """Parameters one token's pass multiplies by, outside the embedding
+    table and the output head: every leaf of ``params`` (the program's
+    layout; arrays or shapes) but ``embed``, a shared block once per use,
+    and of a mixture-of-experts layer's experts ``top_k`` of
+    ``n_experts``."""
+    import jax
+
+    def size(tree) -> int:
+        n = 0
+        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+            keys = [getattr(k, "key", None) for k in path]
+            m = math.prod(leaf.shape)
+            if len(keys) >= 2 and keys[-2] == "moe" and keys[-1] in (
+                    "gate", "up", "down"):
+                m = m * cfg.top_k // cfg.n_experts
+            n += m
+        return n
+
+    rest = size({k: v for k, v in params.items() if k != "embed"})
+    uses = plan_kinds(cfg).count("shared")
+    return rest + (uses - 1) * size(params["shared"]) if uses else rest
+
+
+def chunk_keys(start: int, valid: int, window) -> int:
+    """Keys that positions ``start`` .. ``start + valid - 1`` attend to,
+    each to itself and the keys before it inside ``window``."""
+    lo, hi = start + 1, start + valid     # position p attends p + 1 keys
+    if not window or hi <= window:
+        return valid * start + valid * (valid + 1) // 2
+    if lo > window:
+        return window * valid
+    return (window - lo + 1) * (lo + window) // 2 + window * (hi - window)
+
+
+def flops(cfg, body: int, chunks, decodes) -> float:
+    """Model FLOPs of the calls, from the program's layer plan:
+    2 x ``body`` (``token_params``) per token processed; 2 x vocab x d
+    per logit row computed (one per chunk, one per decoded token), tied
+    head or not; and 4 x heads x head_dim per key that each attention
+    layer of the plan attends to (a shared block once per use, a windowed
+    layer only the keys inside its window, ``min(context, window)``).
+    Mixers that are not attention (SSM) count by their parameters only:
+    their state updates and scans are not counted."""
     d, V = cfg.d_model, cfg.padded_vocab
-    body = n_params - V * d
-    attn = 4 * cfg.n_layers * cfg.n_heads * cfg.hd
+    per_key = 4 * cfg.n_heads * cfg.hd
+    windows = attention_windows(cfg)
     total = 0.0
     for _, start, valid in chunks:
-        # positions start .. start+valid-1 attend to p+1 keys each
-        keys = valid * start + valid * (valid + 1) / 2
-        total += 2 * body * valid + 2 * V * d + attn * keys
+        keys = sum(n * chunk_keys(start, valid, w)
+                   for w, n in windows.items())
+        total += 2 * body * valid + 2 * V * d + per_key * keys
     for _, ctx in decodes:
-        total += len(ctx) * (2 * body + 2 * V * d) + attn * sum(ctx)
+        keys = sum(n * (sum(min(c, w) for c in ctx) if w else sum(ctx))
+                   for w, n in windows.items())
+        total += len(ctx) * (2 * body + 2 * V * d) + per_key * keys
     return total
+
+
+def window_pages(ctx: int, window, page_size: int) -> tuple[int, int]:
+    """(cached keys, pages holding them) that a token decoded at context
+    ``ctx`` (itself included, merged outside the kernel) attends to in a
+    layer with ``window``."""
+    old = ctx - 1
+    keys = min(old, window - 1) if window else old
+    if keys <= 0:
+        return 0, 0
+    return keys, (ctx - 2) // page_size - (ctx - 1 - keys) // page_size + 1
 
 
 def paged_kernel_cost(cfg, page_size: int, decodes) -> tuple[float, float]:
     """(FLOPs, HBM bytes) the paged flash-decode kernel needs for the
-    decode calls: per layer and live slot, the pages that hold its cached
-    keys and values (context minus the new token, which is merged outside
-    the kernel), read once, plus the query and the (acc, m, l) outputs."""
-    L, KV, H, hd = cfg.n_layers, cfg.n_kv_heads, cfg.n_heads, cfg.hd
+    decode calls: per attention layer of the plan and live slot, the pages
+    that hold the cached keys and values it attends to (context minus the
+    new token; in a windowed layer only those inside the window), read
+    once, plus the query and the (acc, m, l) outputs."""
+    import jax.numpy as jnp
+    KV, H, hd = cfg.n_kv_heads, cfg.n_heads, cfg.hd
     G = H // KV
-    page_bytes = page_size * hd * 2           # bf16 K or V, one head
+    windows = attention_windows(cfg)
+    L = sum(windows.values())
+    kv_bytes = jnp.dtype(cfg.kv_cache_dtype or cfg.compute_dtype).itemsize
+    q_bytes = jnp.dtype(cfg.compute_dtype).itemsize
+    page_bytes = page_size * hd * kv_bytes    # K or V, one head
     fl = by = 0.0
     for _, ctx in decodes:
         for c in ctx:
-            old = c - 1
-            pages = -(-old // page_size)
-            by += L * KV * 2 * pages * page_bytes
-            by += L * H * hd * 2 + L * KV * G * (hd + 2 * 128) * 4
-            fl += L * 4 * H * hd * old
+            keys = pages = 0
+            for w, n in windows.items():
+                k, p = window_pages(c, w, page_size)
+                keys, pages = keys + n * k, pages + n * p
+            by += KV * 2 * pages * page_bytes
+            by += L * H * hd * q_bytes + L * KV * G * (hd + 2 * 128) * 4
+            fl += 4 * H * hd * keys
     return fl, by
 
 
@@ -259,6 +359,7 @@ def check(cell, jax, sample) -> None:
     positions, the ones the control (float8 weights, computed in bfloat16)
     puts first."""
     conf = cell.config
+    ref = reference.find(conf["system"])
     t0 = time.perf_counter()
     seqs, rows = [], []
     for r in sample:

@@ -84,7 +84,7 @@ def run(cell, jax) -> None:
                    + sum(1 for r in unsubmitted if t_open <= r["due"] < t_close))
     cell.data.update({"records": records, "open": t_open, "close": t_close,
                       "recorder": rec, "cfg": srv.cfg,
-                      "n_params": srv.n_params,
+                      "body": srv.body,
                       "page_size": srv.engine.page_size,
                       "slots": srv.engine.batch,
                       "max_pages": srv.engine.max_pages,

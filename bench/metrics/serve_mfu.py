@@ -10,5 +10,5 @@ def read(cell):
     if "recorder" not in d:
         return None
     chunks, decodes = d["recorder"].in_span(d["open"], d["close"])
-    fl = serve_common.flops(d["cfg"], d["n_params"], chunks, decodes)
+    fl = serve_common.flops(d["cfg"], d["body"], chunks, decodes)
     return fl / (d["close"] - d["open"]) / cell.peaks["bf16_flops_per_s"] * 100

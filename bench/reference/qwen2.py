@@ -65,6 +65,20 @@ def dims(conf: dict) -> dict:
             "embed_std": float(conf["initializer_range"])}
 
 
+def check_config(cfg, conf: dict) -> None:
+    """Raise where the system's ``ModelConfig`` departs from what a Qwen2
+    configuration states beyond the sizes every decoder has: the MLP
+    width, the RoPE base, q/k/v biases and a head size of hidden / heads."""
+    for key, field in (("intermediate_size", "d_ff"),
+                       ("rope_theta", "rope_theta")):
+        if getattr(cfg, field) != conf[key]:
+            raise ValueError(f"{conf['program_arch']}: {field}="
+                             f"{getattr(cfg, field)!r} but the published "
+                             f"{key} is {conf[key]!r}")
+    if not cfg.qkv_bias or cfg.hd * cfg.n_heads != conf["hidden_size"]:
+        raise ValueError("qwen2 needs q/k/v biases and hidden/heads head size")
+
+
 def _seed_key(jax, seed: int):
     key = jax.random.PRNGKey(seed & 0xFFFFFFFF)
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)

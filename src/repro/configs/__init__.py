@@ -1,5 +1,6 @@
-"""Architecture registry: the 10 assigned configs (+ the paper's Jacobi
-experiment config) selectable via ``--arch <id>``."""
+"""Architecture registry: the 10 assigned configs and the served
+granite-4.0-h-micro (+ the paper's Jacobi experiment config) selectable
+via ``--arch <id>``."""
 from __future__ import annotations
 
 import importlib
@@ -17,6 +18,7 @@ _MODULES = {
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
     "chameleon-34b": "chameleon_34b",
     "mamba2-370m": "mamba2_370m",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCHS = tuple(_MODULES)

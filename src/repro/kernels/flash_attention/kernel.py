@@ -80,8 +80,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_kernel(q, k, v, *, causal: bool = True,
                            window: int | None = None,
                            q_block: int = 512, kv_block: int = 512,
-                           interpret: bool = False):
-    """q: (B, H, S, D); k/v: (B, KV, T, D).  Returns (B, H, S, D)."""
+                           interpret: bool = False, scale=None):
+    """q: (B, H, S, D); k/v: (B, KV, T, D).  Returns (B, H, S, D);
+    ``scale`` None is 1 / sqrt(D)."""
     B, H, S, D = q.shape
     KV, T = k.shape[1], k.shape[2]
     G = H // KV
@@ -89,7 +90,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
     kv_block = min(kv_block, T)
     assert S % q_block == 0 and T % kv_block == 0, (S, q_block, T, kv_block)
     nq, nk = S // q_block, T // kv_block
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, window=window,

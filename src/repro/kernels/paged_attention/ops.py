@@ -74,10 +74,11 @@ def _pages_per_block(page_size: int, max_pages: int) -> int:
 
 def paged_decode_attention(q, k_pool, v_pool, pages, kv_len, kt, vt, *,
                            window: int | None = None, impl: str = "auto",
-                           pages_per_block: int | None = None):
+                           pages_per_block: int | None = None, scale=None):
     """One-token attention straight against a paged KV pool.
 
-    q: (B, 1, H, D); k/v_pool: (P, KV, page_size, D) shared pools; pages:
+    q: (B, 1, H, D); k/v_pool: (P, KV, page_size, D') shared pools (D' >=
+    D: a lane-dense pool pads D with zeros); pages:
     (B, n_pages) int32 per-slot page table (physical page 0 = trash, read
     as zeros); kv_len: scalar or (B,) OLD cache lengths; kt/vt:
     (B, KV, 1, D) current-token K/V.  Returns (B, 1, H, D), numerically
@@ -85,11 +86,13 @@ def paged_decode_attention(q, k_pool, v_pool, pages, kv_len, kt, vt, *,
 
     ``pages_per_block`` pins the kernel's block (tests walk several sizes
     over the same slots); unset, :func:`_pages_per_block` derives it.
+    ``scale``: the softmax scale the query is multiplied by (None:
+    1 / sqrt(D)).
     """
     impl = resolve_paged_impl(impl, k_pool.dtype, q.dtype)
     if impl == "ref":
         return paged_decode_attention_ref(q, k_pool, v_pool, pages, kv_len,
-                                          kt, vt, window=window)
+                                          kt, vt, window=window, scale=scale)
 
     B, _, H, D = q.shape
     KV = k_pool.shape[1]
@@ -99,15 +102,18 @@ def paged_decode_attention(q, k_pool, v_pool, pages, kv_len, kt, vt, *,
 
     kv_len = jnp.broadcast_to(jnp.reshape(jnp.asarray(kv_len), (-1,)),
                               (B,)).astype(jnp.int32)
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     qf = (q.reshape(B, KV, G, D) * scale).astype(q.dtype)
     g_pad = -(-G // _SUBLANE) * _SUBLANE
-    qp = jnp.pad(qf, ((0, 0), (0, 0), (0, g_pad - G), (0, 0)))
+    # a lane-dense pool stores D padded with zeros to its last axis: the
+    # query's zero lanes meet them, and the output is sliced back
+    d_pool = k_pool.shape[-1]
+    qp = jnp.pad(qf, ((0, 0), (0, 0), (0, g_pad - G), (0, d_pool - D)))
     acc, m, l = paged_attention_kernel(
         qp, k_pool, v_pool, pages.astype(jnp.int32), kv_len,
         pages_per_block=pages_per_block, window=window,
         interpret=(impl == "interpret"))
-    acc, m, l = acc[:, :, :G], m[:, :, :G, 0], l[:, :, :G, 0]
+    acc, m, l = acc[:, :, :G, :D], m[:, :, :G, 0], l[:, :, :G, 0]
 
     # LSE merge of the current token's self term (delta-cache contract:
     # kt/vt are not yet in the pool) — mirrors _decode_attn_plus_self

@@ -28,13 +28,15 @@ def gather_pages_ref(pool, pages):
 
 
 def paged_decode_attention_ref(q, k_pool, v_pool, pages, kv_len, kt, vt, *,
-                               window: int | None = None):
+                               window: int | None = None, scale=None):
     """q: (B, 1, H, D); pools: (P, KV, ps, D); pages: (B, n) int32;
     kv_len: scalar or (B,) OLD cache lengths; kt/vt: (B, KV, 1, D) the
-    current token's K/V (merged as a self term).  Returns (B, 1, H, D)."""
-    k_cache = gather_pages_ref(k_pool, pages)
-    v_cache = gather_pages_ref(v_pool, pages)
+    current token's K/V (merged as a self term); ``scale`` None is
+    1 / sqrt(D).  Returns (B, 1, H, D)."""
     B, _, H, D = q.shape
+    # a lane-dense pool pads D with zeros: read the head size back
+    k_cache = gather_pages_ref(k_pool, pages)[..., :D]
+    v_cache = gather_pages_ref(v_pool, pages)[..., :D]
     KV, T = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
     if k_cache.dtype != q.dtype:
@@ -42,7 +44,7 @@ def paged_decode_attention_ref(q, k_pool, v_pool, pages, kv_len, kt, vt, *,
         v_cache = v_cache.astype(q.dtype)
         kt = kt.astype(q.dtype)
         vt = vt.astype(q.dtype)
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     qf = (q.reshape(B, KV, G, D) * scale).astype(q.dtype)
     s_old = jnp.einsum("bkgd,bktd->bkgt", qf, k_cache,
                        preferred_element_type=jnp.float32)

@@ -25,6 +25,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# the operands are float32: at default precision the MXU rounds them to
+# bfloat16, which put the kernel ~5e-3 off its float32 oracle on the chip
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _ssd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, s_ref):
     # x: (1,1,Q,P) dt/cum: (1,1,Q,1) b/c: (1,Q,N)
@@ -41,15 +45,18 @@ def _ssd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, s_ref):
     cols = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     L = jnp.where(rows >= cols, jnp.exp(seg), 0.0)
     CB = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
+                             precision=_HIGHEST,
                              preferred_element_type=jnp.float32)   # (Q, Q)
     M = CB * L
     y = jax.lax.dot_general(M, x * dt, (((1,), (0,)), ((), ())),
+                            precision=_HIGHEST,
                             preferred_element_type=jnp.float32)    # (Q, P)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     decay_end = jnp.exp(cum[-1:, :] - cum)       # (Q, 1)
     Bw = B * (decay_end * dt)                    # (Q, N)
     S = jax.lax.dot_general(Bw, x, (((0,), (0,)), ((), ())),
+                            precision=_HIGHEST,
                             preferred_element_type=jnp.float32)    # (N, P)
     s_ref[0, 0] = S.astype(s_ref.dtype)
 

@@ -300,6 +300,10 @@ def trace_stats(args, sched, snap) -> dict:
         # the decode attention the run actually executed (PagedEngine
         # resolves "auto" from the backend and the pool dtype)
         "paged_attn_impl": eng.attn_impl if sched.paged else None,
+        # recurrent state beside the pages: bytes a slot holds, and the
+        # prefills that started a reused slot's state from zero
+        "ssm_state_bytes": eng.ssm_state_bytes if sched.paged else None,
+        "ssm_state_resets": eng.ssm_state_resets if sched.paged else None,
         # generated ids per request: what mesh/impl parity checks compare
         "outputs": {int(r.rid): [int(t) for t in r.tokens] for r in results},
         "reserve": getattr(sched, "reserve", "lifetime"),

@@ -77,11 +77,17 @@ def _mask_bias(q_pos, k_pos, *, causal: bool, window: int | None, dtype):
 # ---------------------------------------------------------------------------
 
 
-def _dense_attn(q, k, v, q_pos, k_pos, *, causal, window):
+def _scale(scale, D):
+    """The softmax scale: the configuration's ``attn_scale`` where it sets
+    one, else 1 / sqrt(head_dim)."""
+    return 1.0 / math.sqrt(D) if scale is None else scale
+
+
+def _dense_attn(q, k, v, q_pos, k_pos, *, causal, window, scale=None):
     B, S, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
-    qf = q.astype(jnp.float32) * (1.0 / math.sqrt(D))
+    qf = q.astype(jnp.float32) * _scale(scale, D)
     qg = qf.reshape(B, S, KV, G, D)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k.astype(jnp.float32))
     scores = scores + _mask_bias(q_pos, k_pos, causal=causal, window=window,
@@ -92,7 +98,7 @@ def _dense_attn(q, k, v, q_pos, k_pos, *, causal, window):
 
 
 def _tiled_attn(q, k, v, q_pos, k_pos, *, causal, window,
-                q_tile: int = 1024, kv_tile: int = 1024):
+                q_tile: int = 1024, kv_tile: int = 1024, scale=None):
     """Flash-style: online softmax over KV tiles; python loop over q tiles
     (static triangular schedule — fully-masked tiles are never emitted into
     the HLO), ``lax.scan`` over kv tiles inside."""
@@ -112,7 +118,7 @@ def _tiled_attn(q, k, v, q_pos, k_pos, *, causal, window,
     kp = kp.reshape(B, nk, kv_tile, KV, D)
     vp = vp.reshape(B, nk, kv_tile, KV, D)
     kpos_t = kpos.reshape(nk, kv_tile)
-    scale = 1.0 / math.sqrt(D)
+    scale = _scale(scale, D)
 
     outs = []
     for i in range(nq):
@@ -194,7 +200,7 @@ def gather_pages(pool, page_table):
 
 
 def _chunk_attn_with_cache(q, k_cache, v_cache, start, kt, vt, *,
-                           window: int | None = None):
+                           window: int | None = None, scale=None):
     """Chunked-prefill attention: a prompt chunk at positions
     ``start .. start+C-1`` attends over the already-written cache entries
     (positions < start) plus itself (causal within the chunk) — the S>1
@@ -220,8 +226,7 @@ def _chunk_attn_with_cache(q, k_cache, v_cache, start, kt, vt, *,
         v_cache = v_cache.astype(q.dtype)
         kt = kt.astype(q.dtype)
         vt = vt.astype(q.dtype)
-    scale = 1.0 / math.sqrt(D)
-    qf = (q.reshape(B, C, KV, G, D) * scale).astype(q.dtype)
+    qf = (q.reshape(B, C, KV, G, D) * _scale(scale, D)).astype(q.dtype)
     q_pos = start + jnp.arange(C)                                  # (C,)
     rel = q_pos[:, None] - q_pos[None, :]                          # (C, C)
     valid_self = rel >= 0
@@ -250,13 +255,15 @@ def _chunk_attn_with_cache(q, k_cache, v_cache, start, kt, vt, *,
 
     def first_chunk(_):
         return _dense_attn(q, kt.swapaxes(1, 2), vt.swapaxes(1, 2),
-                           q_pos, q_pos, causal=True, window=window)
+                           q_pos, q_pos, causal=True, window=window,
+                           scale=scale)
 
     return jax.lax.cond(jnp.asarray(start) > 0, with_cache, first_chunk,
                         None)
 
 
-def decode_attention(q, k_cache, v_cache, kv_len, *, window: int | None = None):
+def decode_attention(q, k_cache, v_cache, kv_len, *, window: int | None = None,
+                     scale=None):
     """One-token attention against a (possibly sequence-sharded) KV cache.
 
     q: (B, 1, H, D); caches: (B, KV, T, D) — heads-major layout so both dots
@@ -272,7 +279,7 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, window: int | None = None):
     if k_cache.dtype != q.dtype:   # f8-stored caches: cast the layer slice
         k_cache = k_cache.astype(q.dtype)
         v_cache = v_cache.astype(q.dtype)
-    qf = (q.reshape(B, KV, G, D) * (1.0 / math.sqrt(D))).astype(q.dtype)
+    qf = (q.reshape(B, KV, G, D) * _scale(scale, D)).astype(q.dtype)
     s = jnp.einsum("bkgd,bktd->bkgt", qf, k_cache,
                    preferred_element_type=jnp.float32)
     pos = jnp.arange(T)[None, :]
@@ -287,7 +294,7 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, window: int | None = None):
 
 
 def _decode_attn_plus_self(q, k_cache, v_cache, kv_len_old, kt, vt, *,
-                           window: int | None = None):
+                           window: int | None = None, scale=None):
     """Decode attention over the *old* cache entries plus the just-computed
     token's own K/V (kt/vt, (B,KV,1,D)) — so the cache write can happen
     outside, as a pure delta.  Numerically identical to writing first and
@@ -300,8 +307,7 @@ def _decode_attn_plus_self(q, k_cache, v_cache, kv_len_old, kt, vt, *,
         v_cache = v_cache.astype(q.dtype)
         kt = kt.astype(q.dtype)
         vt = vt.astype(q.dtype)
-    scale = 1.0 / math.sqrt(D)
-    qf = (q.reshape(B, KV, G, D) * scale).astype(q.dtype)
+    qf = (q.reshape(B, KV, G, D) * _scale(scale, D)).astype(q.dtype)
     s_old = jnp.einsum("bkgd,bktd->bkgt", qf, k_cache,
                        preferred_element_type=jnp.float32)
     pos = jnp.arange(T)[None, :]
@@ -373,6 +379,7 @@ def attention(cfg: ModelConfig, p: dict, x, *, positions, kv_x=None,
     B, S, _ = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     rope = cfg.use_rope if rope is None else rope
+    scale = cfg.attn_scale
 
     q = dense(p["q"], x, cd).reshape(B, S, H, hd)
     reuse_cached_kv = cache is not None and kv_x is not None
@@ -398,6 +405,9 @@ def attention(cfg: ModelConfig, p: dict, x, *, positions, kv_x=None,
 
     new_cache = None
     if cache is not None:
+        if "k" in cache and cache["k"].shape[-1] != hd:
+            # views of a lane-dense page pool carry D padded with zeros
+            cache = {kk: cache[kk][..., :hd] for kk in ("k", "v")}
         if kv_x is None:
             # DELTA cache contract (§Perf iter 4 — best measured variant):
             # return only this step's K/V; the caller writes them into the
@@ -417,15 +427,15 @@ def attention(cfg: ModelConfig, p: dict, x, *, positions, kv_x=None,
                 out = pa_ops.paged_decode_attention(
                     q, cache["k_pool"], cache["v_pool"], cache["pages"],
                     jnp.asarray(cache_len), kt, vt, window=window,
-                    impl=paged_impl)
+                    impl=paged_impl, scale=scale)
             elif S == 1:
                 out = _decode_attn_plus_self(
                     q, cache["k"], cache["v"], jnp.asarray(cache_len),
-                    kt, vt, window=window)
+                    kt, vt, window=window, scale=scale)
             elif chunk_continue:
                 out = _chunk_attn_with_cache(
                     q, cache["k"], cache["v"], jnp.asarray(cache_len), kt, vt,
-                    window=window)
+                    window=window, scale=scale)
             else:
                 # batched prefill: attend over the freshly computed local
                 # K/V (the cache holds exactly these entries when starting
@@ -434,24 +444,27 @@ def attention(cfg: ModelConfig, p: dict, x, *, positions, kv_x=None,
                 k_pos = jnp.asarray(cache_len) + jnp.arange(S)
                 if S * S <= 4096 * 4096 // 4:
                     out = _dense_attn(q, k, v, q_pos, k_pos,
-                                      causal=True, window=window)
+                                      causal=True, window=window, scale=scale)
                 else:
                     out = _tiled_attn(q, k, v, q_pos, k_pos,
-                                      causal=True, window=window)
+                                      causal=True, window=window, scale=scale)
         else:
             out = decode_attention(q, cache["k"], cache["v"], cache_len,
-                                   window=None)
+                                   window=None, scale=scale)
             new_cache = cache
     else:
         q_pos = positions[0] if positions.ndim > 1 else positions
         k_pos = kv_pos[0] if kv_pos.ndim > 1 else kv_pos
         if impl == "pallas":
             from repro.kernels.flash_attention import ops as fa_ops
-            out = fa_ops.flash_attention(q, k, v, causal=causal, window=window)
+            out = fa_ops.flash_attention(q, k, v, causal=causal, window=window,
+                                         scale=scale)
         elif S * k.shape[1] <= 4096 * 4096 // 4 or impl == "dense":
-            out = _dense_attn(q, k, v, q_pos, k_pos, causal=causal, window=window)
+            out = _dense_attn(q, k, v, q_pos, k_pos, causal=causal,
+                              window=window, scale=scale)
         else:
-            out = _tiled_attn(q, k, v, q_pos, k_pos, causal=causal, window=window)
+            out = _tiled_attn(q, k, v, q_pos, k_pos, causal=causal,
+                              window=window, scale=scale)
 
     out = dense(p["o"], out.reshape(B, S, H * hd), cd)
     return out, new_cache

@@ -49,8 +49,13 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    ssm_mlp: bool = False                 # a gated MLP after each Mamba-2
+                                          # mixer (granite-4.0-h)
     # --- hybrid (zamba2): one shared attention block every k SSM blocks ---
     hybrid_attn_every: int = 0
+    # --- hybrid by layer kind (granite-4.0-h): the block kinds of one
+    # period, repeated over the depth; () -> the family's own plan ---
+    layer_pattern: tuple[str, ...] = ()
     # --- encoder-decoder (whisper) ---
     n_encoder_layers: int = 0
     decoder_len: int = 448                # whisper max target positions
@@ -59,6 +64,13 @@ class ModelConfig:
     norm: str = "rmsnorm"                 # rmsnorm | layernorm
     tie_embeddings: bool = True
     embed_scale: bool = False             # gemma: embeddings * sqrt(d_model)
+    norm_eps: float = 1e-6
+    # granite's scalars; the defaults emit no op (a multiplier of 1 is not
+    # multiplied, attn_scale None is 1 / sqrt(head_dim))
+    embed_multiplier: float = 1.0         # embeddings * m
+    attn_scale: float | None = None       # softmax(q k^T * attn_scale)
+    residual_multiplier: float = 1.0      # x + branch(x) * m, every branch
+    logits_divisor: float = 1.0           # logits / m
     max_seq: int = 131_072
     # --- numerics / training ---
     param_dtype: str = "float32"
@@ -108,7 +120,19 @@ class ModelConfig:
             attn += hd * (H + 2 * KV)
         mlp_dense = 3 * d * ff                                  # gate,up,down
         per_layer = 0
-        if self.family == "ssm":
+        if self.layer_pattern:
+            di, s, nh = self.ssm_d_inner, self.ssm_state, self.ssm_n_heads
+            # in_proj, conv (weights + bias), A_log, D, dt_bias, gated norm,
+            # out_proj, pre-norm; then the MLP and its norm where present
+            mixer = d * (2 * di + 2 * s + nh) + (self.ssm_conv + 1) * (
+                di + 2 * s) + 3 * nh + di + di * d + d
+            if self.ssm_mlp:
+                mixer += mlp_dense + d
+            kinds = [self.layer_pattern[i % len(self.layer_pattern)]
+                     for i in range(self.n_layers)]
+            total = sum(mixer if k == "ssm" else attn + mlp_dense + 2 * d
+                        for k in kinds)
+        elif self.family == "ssm":
             di, s = self.ssm_d_inner, self.ssm_state
             ng = max(1, self.ssm_n_heads // 8)  # group count heuristic unused
             # in_proj: d -> 2*di + 2*state + n_heads(dt); out_proj: di -> d

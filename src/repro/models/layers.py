@@ -36,9 +36,12 @@ def init_norm(cfg: ModelConfig, shape_d: int | None = None) -> dict:
     return p
 
 
-def apply_norm(cfg: ModelConfig, p: dict, x: jax.Array, *, eps: float = 1e-6,
+def apply_norm(cfg: ModelConfig, p: dict, x: jax.Array, *,
+               eps: float | None = None,
                use_kernel: bool = False) -> jax.Array:
-    """RMSNorm / LayerNorm in fp32 accumulations, output in x.dtype."""
+    """RMSNorm / LayerNorm in fp32 accumulations, output in x.dtype;
+    ``eps`` defaults to the configuration's ``norm_eps``."""
+    eps = cfg.norm_eps if eps is None else eps
     if use_kernel and cfg.norm == "rmsnorm":
         from repro.kernels.rmsnorm import ops as rms_ops
         return rms_ops.rmsnorm(x, p["scale"].astype(jnp.float32), eps=eps)
@@ -122,13 +125,18 @@ def embed(cfg: ModelConfig, p: dict, tokens: jax.Array) -> jax.Array:
     x = jnp.take(p["table"].astype(cd), tokens, axis=0)
     if cfg.embed_scale:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), cd)
+    if cfg.embed_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embed_multiplier, cd)
     return x
 
 
 def unembed(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     cd = jnp.dtype(cfg.compute_dtype)
     table = p.get("unembed", p["table"])
-    return x.astype(cd) @ table.astype(cd).T
+    logits = x.astype(cd) @ table.astype(cd).T
+    if cfg.logits_divisor != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_divisor, cd)
+    return logits
 
 
 # -- RoPE -------------------------------------------------------------------------

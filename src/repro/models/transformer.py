@@ -12,7 +12,8 @@ Block kinds:
   local   — sliding-window attention + MLP   (gemma3 local layers)
   global  — full attention + MLP             (gemma3 global layers)
   moe     — attention + mixture-of-experts   (mixtral [SWA], qwen2-moe)
-  ssm     — Mamba-2 mixer                    (mamba2, zamba2 backbone)
+  ssm     — Mamba-2 mixer [+ gated MLP]      (mamba2, zamba2 backbone;
+            granite-4.0-h with ``ssm_mlp``)
   shared  — zamba2's *shared* attention+MLP block (one parameter set,
             invoked at every occurrence)
   enc/dec — whisper encoder / decoder (cross-attention) blocks
@@ -29,7 +30,7 @@ import jax.numpy as jnp
 
 from repro.parallel.sharding import current_rules, logical
 from .attention import KVCache, attention, gather_pages, init_attention
-from .config import ModelConfig
+from .config import ModelConfig, round_up
 from .layers import (apply_mlp, apply_norm, embed, init_embedding, init_mlp,
                      init_norm, truncated_normal, unembed)
 from .moe import apply_moe, init_moe
@@ -62,6 +63,12 @@ class LayerPlan:
 
 def layer_plan(cfg: ModelConfig) -> LayerPlan:
     L = cfg.n_layers
+    if cfg.layer_pattern:
+        # a period of block kinds (granite-4.0-h: 5 ssm, 1 dense, 4 ssm)
+        k = len(cfg.layer_pattern)
+        g, r = divmod(L, k)
+        return LayerPlan(pattern=tuple(cfg.layer_pattern), n_groups=g,
+                         tail=tuple(cfg.layer_pattern[:r]))
     if cfg.family == "encdec":
         return LayerPlan(pattern=("dec",), n_groups=L, tail=(),
                          enc_pattern=("enc",), enc_groups=cfg.n_encoder_layers)
@@ -94,7 +101,11 @@ def _has_attn(kind: str) -> bool:
 def init_block(key, cfg: ModelConfig, kind: str) -> dict:
     ks = jax.random.split(key, 6)
     if kind == "ssm":
-        return {"ln1": init_norm(cfg), "mixer": init_mamba2(ks[0], cfg)}
+        p = {"ln1": init_norm(cfg), "mixer": init_mamba2(ks[0], cfg)}
+        if cfg.ssm_mlp:
+            p["ln2"] = init_norm(cfg)
+            p["mlp"] = init_mlp(ks[1], cfg)
+        return p
     p = {"ln1": init_norm(cfg), "attn": init_attention(ks[0], cfg),
          "ln2": init_norm(cfg)}
     if kind == "moe":
@@ -113,6 +124,14 @@ def _window_for(cfg: ModelConfig, kind: str) -> int | None:
     if kind in ("dense", "moe", "global", "shared"):
         return cfg.sliding_window if kind in ("dense", "moe") else None
     return None
+
+
+def _branch(cfg: ModelConfig, out):
+    """A residual branch's output, scaled by ``residual_multiplier`` (no op
+    at 1)."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.residual_multiplier, out.dtype)
+    return out
 
 
 def apply_block(cfg: ModelConfig, kind: str, p: dict, x, *, positions,
@@ -150,7 +169,11 @@ def apply_block(cfg: ModelConfig, kind: str, p: dict, x, *, positions,
                                           return_cache=True)
         else:
             out = apply_mamba2(cfg, p["mixer"], h)
-        return x + out, new_cache, aux
+        x = x + _branch(cfg, out)
+        if "mlp" in p:
+            h = apply_norm(cfg, p["ln2"], x)
+            x = x + _branch(cfg, apply_mlp(cfg, p["mlp"], h))
+        return x, new_cache, aux
 
     causal = kind != "enc"
     window = _window_for(cfg, kind)
@@ -162,7 +185,7 @@ def apply_block(cfg: ModelConfig, kind: str, p: dict, x, *, positions,
                             paged_impl=paged_impl,
                             chunk_continue=chunk_continue,
                             rope=cfg.use_rope and kind != "enc" and kind != "dec")
-    x = x + logical(out, "batch", "seq", "embed")
+    x = x + logical(_branch(cfg, out), "batch", "seq", "embed")
 
     if kind == "dec" and enc_out is not None:
         h = apply_norm(cfg, p["ln_cross"], x)
@@ -180,7 +203,7 @@ def apply_block(cfg: ModelConfig, kind: str, p: dict, x, *, positions,
         out, aux = apply_moe(cfg, p["moe"], h)
     else:
         out = apply_mlp(cfg, p["mlp"], h)
-    x = x + logical(out, "batch", "seq", "embed")
+    x = x + logical(_branch(cfg, out), "batch", "seq", "embed")
 
     if cache is not None and kind != "ssm":
         # return ONLY the update (deltas) — returning the old cache slices
@@ -398,6 +421,13 @@ def _write_kv(buf, delta, pos, *, batch_axis: int):
                     out_axes=batch_axis)(buf, delta, idx)
 
 
+def _pad_lanes(x, width: int):
+    """``x`` padded with zeros on its last axis to ``width``: the head size
+    a lane-dense page pool stores (``init_paged_cache``)."""
+    pad = width - x.shape[-1]
+    return x if pad == 0 else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def _write_kv_paged(pool, delta, pos, pages, page_size, *, stacked: bool):
     """Scatter one decode step's K/V delta into a shared page pool.
 
@@ -414,7 +444,7 @@ def _write_kv_paged(pool, delta, pos, pages, page_size, *, stacked: bool):
     pid = jnp.take_along_axis(pages, (pos // page_size)[:, None],
                               axis=1)[:, 0]                       # (B,)
     off = pos % page_size
-    val = delta.astype(pool.dtype)
+    val = _pad_lanes(delta.astype(pool.dtype), pool.shape[-1])
     if stacked:
         # (G, B, KV, 1, D) -> (B, G, KV, D); advanced indices (pid, off)
         # are separated by slices, so the batch axis moves to the front
@@ -550,15 +580,22 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *, enc_len: int = 0):
 
 
 def init_paged_cache(cfg: ModelConfig, batch: int, *, num_pages: int,
-                     page_size: int, enc_len: int = 0):
+                     page_size: int, enc_len: int = 0,
+                     lane_dense: bool = False):
     """Paged serving cache: attention layers hold ONE shared page pool
     (P, KV, page_size, D) per k/v instead of per-slot (B, KV, max_len, D)
     buffers — memory scales with pages in use, not batch × worst-case
     request.  SSM states are O(1) per slot and stay dense (B, …).  The
     page table mapping slots to pool pages lives host-side in the serving
-    engine and is passed into each jitted program (DESIGN.md §9)."""
+    engine and is passed into each jitted program (DESIGN.md §9).
+
+    ``lane_dense``: store D padded with zeros to whole 128-lane tiles, as
+    the paged kernel's page copies need (its pages must span whole tiles
+    of the layout it reads the pool in); a head size of 64 so takes twice
+    the pool's memory.  Writes pad, reads slice back to the head size."""
     plan = layer_plan(cfg)
     dtype = jnp.dtype(cfg.kv_cache_dtype or cfg.compute_dtype)
+    D = round_up(cfg.hd, 128) if lane_dense else cfg.hd
     if cfg.family == "encdec":
         raise NotImplementedError("paged KV for encdec (cross-attention "
                                   "buffers) is not implemented")
@@ -567,10 +604,8 @@ def init_paged_cache(cfg: ModelConfig, batch: int, *, num_pages: int,
         if kind == "ssm":
             return SSMCache.init(cfg, batch)
         return {"self": {
-            "k": jnp.zeros((num_pages, cfg.n_kv_heads, page_size, cfg.hd),
-                           dtype),
-            "v": jnp.zeros((num_pages, cfg.n_kv_heads, page_size, cfg.hd),
-                           dtype),
+            "k": jnp.zeros((num_pages, cfg.n_kv_heads, page_size, D), dtype),
+            "v": jnp.zeros((num_pages, cfg.n_kv_heads, page_size, D), dtype),
         }}
 
     def stacked_cache(kind):
@@ -719,6 +754,7 @@ def _write_kv_chunk_paged(pool, delta, start, pages_1d, page_size, *,
     """
     C = delta.shape[-2]
     n = C // page_size
+    delta = _pad_lanes(delta, pool.shape[-1])
     pids = jax.lax.dynamic_slice_in_dim(pages_1d, start // page_size, n)
     if stacked:
         G, _, KV, _, D = delta.shape

@@ -392,7 +392,12 @@ class PagedEngine:
         # a chunk prefill rebuilds position by position — there is no page
         # of it to hand a second request
         plan = layer_plan(cfg)
-        self.supports_prefix_cache = "ssm" not in plan.pattern + plan.tail
+        self._has_ssm = "ssm" in plan.pattern + plan.tail
+        self.supports_prefix_cache = not self._has_ssm
+        # chunks that started a reused slot's recurrent state from zero
+        # (``chunk_prefill_step`` zeroes it at a prompt's first chunk)
+        self.ssm_state_resets = 0
+        self._slot_used = np.zeros((batch,), bool)
         # multi-device serving (DESIGN.md §13): with a mesh, the page pools
         # shard kv_heads->model (TP) and pages/slots->data (DP groups); the
         # three jitted programs trace inside a use_rules context so the
@@ -485,11 +490,29 @@ class PagedEngine:
         if self.cache is None:
             cache = init_paged_cache(self.cfg, self.batch,
                                      num_pages=self.num_pages,
-                                     page_size=self.page_size)
+                                     page_size=self.page_size,
+                                     lane_dense=self.attn_impl == "kernel")
             if self._rules is not None:
                 from .mesh import shard_paged_cache
                 cache = shard_paged_cache(cache, self._rules)
             self.cache = cache
+
+    @property
+    def ssm_state_bytes(self) -> int:
+        """Bytes of recurrent state one slot holds: the SSM layers' SSD
+        state and conv tail, from the cache tree's leaves (0 where the
+        model has no SSM layer).  Read off the shapes; nothing is
+        allocated."""
+        cache = self.cache if self.cache is not None else jax.eval_shape(
+            functools.partial(init_paged_cache, self.cfg, self.batch,
+                              num_pages=self.num_pages,
+                              page_size=self.page_size))
+        total = sum(
+            leaf.size * leaf.dtype.itemsize
+            for part in ("groups", "tail") for bc in cache[part]
+            if isinstance(bc, dict) and "state" in bc
+            for leaf in jax.tree.leaves(bc))
+        return total // self.batch
 
     def per_device_pool_bytes(self) -> int:
         """Max attention-pool bytes resident on any one device (equals the
@@ -537,6 +560,9 @@ class PagedEngine:
         last token — only the final chunk's are meaningful."""
         self.ensure_batch()
         ids = self._check_page_row(slot, page_ids)
+        if start == 0 and self._has_ssm:
+            self.ssm_state_resets += int(self._slot_used[slot])
+            self._slot_used[slot] = True
         row = np.zeros((1, self.max_pages), np.int32)
         row[0, :len(ids)] = ids
         with self._rules_ctx():

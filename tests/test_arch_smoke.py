@@ -93,6 +93,7 @@ def test_full_configs_match_published_sizes():
         "qwen2-moe-a2.7b": (13e9, 15e9),
         "chameleon-34b": (33e9, 35.5e9),
         "mamba2-370m": (0.3e9, 0.45e9),
+        "granite-4.0-h-micro": (3.0e9, 3.4e9),
     }
     for arch in ARCHS:
         n = get_config(arch).n_params()

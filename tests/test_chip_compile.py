@@ -4,14 +4,16 @@ Interpret mode runs a Pallas kernel's logic on the CPU but not the chip's
 compiler, which refuses blocks that break the (8, 128) tiling, primitives
 it cannot lower, and programs that do not fit.  These tests hand the TPU
 compiler the kernels and serving steps at the widths the system runs them
-(qwen2-1.5b, mamba2-370m, the paper's largest Jacobi system) as shapes
-only: nothing runs and nothing is timed.
+(qwen2-1.5b, mamba2-370m, granite-4.0-h-micro, the paper's largest Jacobi
+system) as shapes only: nothing runs and nothing is timed.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports every test file.
 """
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -137,6 +139,80 @@ def test_ssd_intra_chunk_compiles_at_mamba2_widths(one_chip):
         _spec(one_chip, (BC, H, Q, 1), f32),
         _spec(one_chip, (BC, Q, N), f32),
         _spec(one_chip, (BC, Q, N), f32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _hlo_text(compiled) -> str:
+    """The compiled module's text as the profiler names its ops: with the
+    operands' shapes."""
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    return compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+
+
+def test_ssd_intra_chunk_compiles_at_granite_widths(one_chip):
+    """One 512-token prefill chunk of granite-4.0-h-micro (H 64, P 64,
+    N 128, Q 256).  The benchmark's SSD roofline reader finds the kernel
+    by its five operands in this order and these shapes."""
+    cfg = get_config("granite-4.0-h-micro")
+    H, P, N, Q = (cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                  cfg.ssm_chunk)
+    assert (H, P, N, Q) == (64, 64, 128, 256)
+    BC = 512 // Q
+    f32 = jnp.float32
+    fn = functools.partial(ssd_intra_chunk, impl="kernel")
+    compiled = jax.jit(fn).lower(
+        _spec(one_chip, (BC, H, Q, P), f32),
+        _spec(one_chip, (BC, H, Q, 1), f32),
+        _spec(one_chip, (BC, H, Q, 1), f32),
+        _spec(one_chip, (BC, Q, N), f32),
+        _spec(one_chip, (BC, Q, N), f32)).compile()
+    calls = [ln for ln in _hlo_text(compiled).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    shapes = [f"f32[{BC},{H},{Q},{P}]", f"f32[{BC},{H},{Q},1]",
+              f"f32[{BC},{H},{Q},1]", f"f32[{BC},{Q},{N}]",
+              f"f32[{BC},{Q},{N}]"]
+    args = calls[0].split("custom-call(", 1)[1].split("custom_call_target")[0]
+    assert re.findall(r"f32\[[\d,]*\]", args) == shapes
+
+
+def test_paged_attention_kernel_compiles_at_granite_widths(one_chip):
+    """Head size 64: the pool is lane-dense (padded to 128), as the
+    engine allocates it for the kernel; an unpadded pool's page copies
+    are refused."""
+    cfg = get_config("granite-4.0-h-micro")
+    H, KV, D = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    slots, max_pages, pool = 32, 320, 10_241
+    bf16 = jnp.bfloat16
+    args = (_spec(one_chip, (slots, 1, H, D), bf16),
+            _spec(one_chip, (pool, KV, PAGE, 128), bf16),
+            _spec(one_chip, (pool, KV, PAGE, 128), bf16),
+            _spec(one_chip, (slots, max_pages), jnp.int32),
+            _spec(one_chip, (slots,), jnp.int32),
+            _spec(one_chip, (slots, KV, 1, D), bf16),
+            _spec(one_chip, (slots, KV, 1, D), bf16))
+    fn = functools.partial(paged_decode_attention, impl="kernel",
+                           scale=cfg.attn_scale)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert f"custom-call(s32[{slots},{max_pages}]" in _hlo_text(compiled)
+
+
+def test_granite_paged_decode_step_compiles_with_kernel(one_chip):
+    cfg = dataclasses.replace(get_config("granite-4.0-h-micro"),
+                              param_dtype="bfloat16")
+    params = _on(one_chip, jax.eval_shape(functools.partial(init_params, cfg),
+                                          jax.random.PRNGKey(0)))
+    n_pages = -(-MAX_LEN // PAGE)
+    cache = _on(one_chip, jax.eval_shape(functools.partial(
+        init_paged_cache, cfg, BATCH, num_pages=1 + BATCH * n_pages,
+        page_size=PAGE, lane_dense=True)))
+    step = functools.partial(decode_step, cfg, page_size=PAGE,
+                             paged_impl="kernel")
+    compiled = jax.jit(step).lower(
+        params, cache, _spec(one_chip, (BATCH, 1), jnp.int32),
+        pages=_spec(one_chip, (BATCH, n_pages), jnp.int32),
+        update_mask=_spec(one_chip, (BATCH,), jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

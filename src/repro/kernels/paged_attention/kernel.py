@@ -2,17 +2,19 @@
 
 vLLM-style paged attention (DESIGN.md §15): one query token per slot
 attends over that slot's KV pages *in place* in the shared page pool.  The
-per-slot page table and per-slot cache lengths ride in as scalar-prefetch
-operands (``pltpu.PrefetchScalarGridSpec``, the table first); the pools
-stay in HBM (``memory_space=pl.ANY``) and the kernel copies the pages it
-needs itself, so no dense per-slot gather is ever materialised.
+per-slot page table, per-slot cache lengths and the layer index ride in as
+scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``, the table
+first); the pools of every layer, stacked ``(n_layers, P, KV, page_size, D)``,
+stay in HBM (``memory_space=pl.ANY``) and the kernel copies the pages of
+its layer itself, so neither a dense per-slot gather nor a copy of one
+layer's pool is ever materialised.
 
 Grid: one step per slot.  Inside it a loop runs over blocks of
 ``pages_per_block`` pages, from the slot's first live page (0, or the page
 holding the sliding window's start) to its last (``ceil(kv_len /
 page_size)``): the work follows each slot's live pages, not the table's
 width, and a slot with ``kv_len == 0`` costs one empty step and no copy.
-Each page of the ``(P, KV, page_size, D)`` pool holds both kv heads
+Each page of a layer's ``(P, KV, page_size, D)`` pool holds both kv heads
 contiguously, so one DMA per page brings both heads of K (and one of V)
 into a VMEM block buffer ``(KV, pages_per_block * page_size, D)``.  The
 buffers are doubled: the next block, of this slot or of the next slot with
@@ -48,7 +50,7 @@ NEG_INF = -2.3819763e38
 LANE = 128   # the (m, l) outputs broadcast over a full lane dim
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
+def _paged_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
                   acc_ref, m_ref, l_ref, k_buf, v_buf, zero_page, sem,
                   state, *,
                   window: int | None, page_size: int, ppb: int):
@@ -57,6 +59,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
     n_pages = tbl_ref.shape[1]
     KV, g_pad, D = q_ref.shape[1:]
     ps = page_size
+    layer = layer_ref[0]
 
     def live_pages(slot):
         """[first, last) logical pages of ``slot`` holding a valid key."""
@@ -86,7 +89,8 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
             for pool, vmem in ((k_hbm, k_buf), (v_hbm, v_buf)):
                 @pl.when(pid != 0)
                 def _copy():
-                    pltpu.make_async_copy(pool.at[pid], vmem.at[buf, :, rows],
+                    pltpu.make_async_copy(pool.at[layer, pid],
+                                          vmem.at[buf, :, rows],
                                           sem.at[buf]).start()
 
                 @pl.when(pid == 0)
@@ -182,14 +186,15 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
         l_ref[0, h] = jnp.broadcast_to(l, (g_pad, LANE))
 
 
-def paged_attention_kernel(q, k_pool, v_pool, pages, kv_len, *,
+def paged_attention_kernel(q, k_pool, v_pool, pages, kv_len, layer, *,
                            pages_per_block: int,
                            window: int | None = None,
                            interpret: bool = False):
     """q: (B, KV, g_pad, D) pre-scaled grouped queries; k/v_pool:
-    (P, KV, page_size, D) shared pools; pages: (B, n_pages) int32 page
-    table; kv_len: (B,) int32 valid lengths (OLD lengths — the current
-    token's self term is merged outside); ``pages_per_block``: pages per
+    (n, P, KV, page_size, D) shared pools of n layers; pages: (B, n_pages)
+    int32 page table; kv_len: (B,) int32 valid lengths (OLD lengths — the
+    current token's self term is merged outside); layer: (1,) int32, the
+    layer whose pages are read; ``pages_per_block``: pages per
     copy-and-compute block.
 
     Returns ``(acc, m, l)``: unnormalised f32 accumulator
@@ -197,14 +202,14 @@ def paged_attention_kernel(q, k_pool, v_pool, pages, kv_len, *,
     axis, (B, KV, g_pad, LANE).
     """
     B, KV, g_pad, D = q.shape
-    ps = k_pool.shape[2]
+    ps = k_pool.shape[3]
     ppb = pages_per_block
 
     kernel = functools.partial(_paged_kernel, window=window, page_size=ps,
                                ppb=ppb)
-    per_slot = lambda b, tbl, lens: (b, 0, 0, 0)
+    per_slot = lambda b, tbl, lens, layer: (b, 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, KV, g_pad, D), per_slot),
@@ -237,4 +242,4 @@ def paged_attention_kernel(q, k_pool, v_pool, pages, kv_len, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(pages, kv_len, q, k_pool, v_pool)
+    )(pages, kv_len, layer, q, k_pool, v_pool)

@@ -4,8 +4,11 @@
 ``gather_pages`` + ``_decode_attn_plus_self`` pair: same inputs the serving
 engine already holds (pool, page table, per-slot lengths, the current
 token's K/V delta), same (B, 1, H, D) output, no materialised per-slot
-view.  The kernel walks each slot's live pages only, one slot per grid
-step, copying ``pages_per_block`` pages of both kv heads at a time
+view.  The pool may be one layer's or the stacked pools of every layer
+with a layer index: the kernel reads that layer's pages in place, so a
+layer scan hands it the whole stack and no layer's pool is copied out.
+The kernel walks each slot's live pages only, one slot per grid step,
+copying ``pages_per_block`` pages of both kv heads at a time
 (``kernel.py``); the block size follows from the shapes
 (:func:`_pages_per_block`), not from a tune cache.  The kernel returns
 unnormalised (acc, m, l); the current token's self term is LSE-merged here
@@ -73,12 +76,15 @@ def _pages_per_block(page_size: int, max_pages: int) -> int:
 
 
 def paged_decode_attention(q, k_pool, v_pool, pages, kv_len, kt, vt, *,
-                           window: int | None = None, impl: str = "auto",
+                           layer=0, window: int | None = None,
+                           impl: str = "auto",
                            pages_per_block: int | None = None, scale=None):
     """One-token attention straight against a paged KV pool.
 
-    q: (B, 1, H, D); k/v_pool: (P, KV, page_size, D') shared pools (D' >=
-    D: a lane-dense pool pads D with zeros); pages:
+    q: (B, 1, H, D); k/v_pool: (P, KV, page_size, D') one layer's shared
+    pools, or (n, P, KV, page_size, D') n layers' read at ``layer`` (an int
+    or a traced int32 scalar; D' >= D: a lane-dense pool pads D with
+    zeros); pages:
     (B, n_pages) int32 per-slot page table (physical page 0 = trash, read
     as zeros); kv_len: scalar or (B,) OLD cache lengths; kt/vt:
     (B, KV, 1, D) current-token K/V.  Returns (B, 1, H, D), numerically
@@ -89,16 +95,19 @@ def paged_decode_attention(q, k_pool, v_pool, pages, kv_len, kt, vt, *,
     ``scale``: the softmax scale the query is multiplied by (None:
     1 / sqrt(D)).
     """
+    if k_pool.ndim == 4:            # one layer's pools: a stack of one
+        k_pool, v_pool = k_pool[None], v_pool[None]
     impl = resolve_paged_impl(impl, k_pool.dtype, q.dtype)
     if impl == "ref":
-        return paged_decode_attention_ref(q, k_pool, v_pool, pages, kv_len,
-                                          kt, vt, window=window, scale=scale)
+        return paged_decode_attention_ref(q, k_pool[layer], v_pool[layer],
+                                          pages, kv_len, kt, vt,
+                                          window=window, scale=scale)
 
     B, _, H, D = q.shape
-    KV = k_pool.shape[1]
+    KV = k_pool.shape[2]
     G = H // KV
     if pages_per_block is None:
-        pages_per_block = _pages_per_block(k_pool.shape[2], pages.shape[1])
+        pages_per_block = _pages_per_block(k_pool.shape[3], pages.shape[1])
 
     kv_len = jnp.broadcast_to(jnp.reshape(jnp.asarray(kv_len), (-1,)),
                               (B,)).astype(jnp.int32)
@@ -111,6 +120,7 @@ def paged_decode_attention(q, k_pool, v_pool, pages, kv_len, kt, vt, *,
     qp = jnp.pad(qf, ((0, 0), (0, 0), (0, g_pad - G), (0, d_pool - D)))
     acc, m, l = paged_attention_kernel(
         qp, k_pool, v_pool, pages.astype(jnp.int32), kv_len,
+        jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
         pages_per_block=pages_per_block, window=window,
         interpret=(impl == "interpret"))
     acc, m, l = acc[:, :, :G, :D], m[:, :, :G, 0], l[:, :, :G, 0]

@@ -370,9 +370,10 @@ def attention(cfg: ModelConfig, p: dict, x, *, positions, kv_x=None,
     path the serving engine gathers per-slot views (``gather_pages``) into
     the dense (B, KV, T, D) layout before the block runs, so reads here are
     layout-agnostic; on the kernel path (``paged_impl`` in
-    kernel/interpret, S == 1) the cache instead carries the raw pools plus
-    the page table (``k_pool``/``v_pool``/``pages``) and the paged
-    flash-decode kernel resolves pages inside its index map — no gather.
+    kernel/interpret, S == 1) the cache instead carries the raw pools of
+    every layer plus the page table and this layer's index
+    (``k_pool``/``v_pool``/``pages``/``layer``) and the paged flash-decode
+    kernel copies this layer's pages itself — no gather, no slice.
     Writes stay deltas either way.
     """
     cd = jnp.dtype(cfg.compute_dtype)
@@ -426,8 +427,8 @@ def attention(cfg: ModelConfig, p: dict, x, *, positions, kv_x=None,
                 from repro.kernels.paged_attention import ops as pa_ops
                 out = pa_ops.paged_decode_attention(
                     q, cache["k_pool"], cache["v_pool"], cache["pages"],
-                    jnp.asarray(cache_len), kt, vt, window=window,
-                    impl=paged_impl, scale=scale)
+                    jnp.asarray(cache_len), kt, vt, layer=cache["layer"],
+                    window=window, impl=paged_impl, scale=scale)
             elif S == 1:
                 out = _decode_attn_plus_self(
                     q, cache["k"], cache["v"], jnp.asarray(cache_len),

@@ -146,9 +146,10 @@ def apply_block(cfg: ModelConfig, kind: str, p: dict, x, *, positions,
     ``valid_len``: true (unpadded) length of a bucketed prompt chunk.
     Paged serving engines pass attention caches either as pre-gathered
     per-slot VIEWS in the dense layout (reference path, see
-    ``decode_step``) or — on the paged-kernel decode path — as raw pools
-    plus the page table under ``k_pool``/``v_pool``/``pages``, which the
-    attention layer hands to the paged flash-decode kernel.
+    ``decode_step``) or — on the paged-kernel decode path — as the raw
+    pools of every layer plus the page table and the layer's index under
+    ``k_pool``/``v_pool``/``pages``/``layer``, which the attention layer
+    hands to the paged flash-decode kernel.
     """
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
@@ -455,6 +456,34 @@ def _write_kv_paged(pool, delta, pos, pages, page_size, *, stacked: bool):
     return logical(out, "pages", "kv_heads", None, None)
 
 
+def _write_kv_pages(pool, delta, pos, pages, page_size, *, stacked: bool):
+    """``_write_kv_paged`` by whole pages, in the pool's own layout.
+
+    Each slot's current page is gathered, the new token's row set in it,
+    and the pages scattered back whole at their ids: the scatter indexes
+    the page axis alone, as the chunk write does, so it runs in place in
+    the pool's layout, where a row scatter indexing the page and the
+    in-page offset makes XLA convert the whole pool to another layout and
+    back.  Live slots never share the page they write (a shared partial
+    page is copied first), so the pages scattered are distinct, but for
+    the trash page, where every free slot writes.
+    """
+    pos = jnp.broadcast_to(jnp.asarray(pos), (pages.shape[0],))
+    pid = jnp.take_along_axis(pages, (pos // page_size)[:, None],
+                              axis=1)[:, 0]                       # (B,)
+    off = pos % page_size
+    val = _pad_lanes(delta.astype(pool.dtype), pool.shape[-1])
+    if not stacked:
+        pool, val = pool[None], val[None]
+    page = pool[:, pid]                                   # (G, B, KV, ps, D)
+    row = jnp.arange(page_size)[None, :] == off[:, None]  # (B, ps)
+    page = jnp.where(row[None, :, None, :, None], val, page)
+    out = pool.at[:, pid].set(page)
+    if not stacked:
+        return logical(out[0], "pages", "kv_heads", None, None)
+    return logical(out, None, "pages", "kv_heads", None, None)
+
+
 def _page_view_block(block_cache, pages):
     """Replace a block's attention page pools by per-slot gathered views in
     the dense (…, B, KV, T, D) layout; non-attention caches (SSM state) and
@@ -471,28 +500,32 @@ def _page_views(block_caches, pages):
     return tuple(_page_view_block(bc, pages) for bc in block_caches)
 
 
-def _page_pool_view_block(block_cache, pages, *, stacked: bool):
+def _attn_pools(block_cache):
+    """A block cache's attention pools ``{"k", "v"}``, or None for a block
+    without attention (SSM state)."""
+    if isinstance(block_cache, dict) and "self" in block_cache:
+        return block_cache["self"]
+    return None
+
+
+def _page_pool_view_block(block_cache, pages, pools, layer):
     """Kernel-path counterpart of ``_page_view_block``: instead of
-    materialising the gathered dense view, pass the raw pools through with
-    the page table alongside (``k_pool``/``v_pool``/``pages``) so the paged
-    flash-decode kernel resolves pages inside its BlockSpec index map.  For
-    stacked (group-scanned) caches the table is broadcast over the layer
-    axis so the scan slices it back out per layer — a (G, B, n_pages) int32
-    broadcast, trivially small next to the gather it replaces."""
-    if pages is None or not (isinstance(block_cache, dict)
-                             and "self" in block_cache):
+    materialising the gathered dense view, pass the pools of every layer
+    through whole, with the page table and the layer's index alongside
+    (``k_pool``/``v_pool``/``pages``/``layer``), so the paged flash-decode
+    kernel copies that layer's pages in place.  ``pools`` None (a block
+    without attention) passes ``block_cache`` through."""
+    if pools is None:
         return block_cache
-    sp = block_cache["self"]
-    pg = pages
-    if stacked:
-        pg = jnp.broadcast_to(pages, (sp["k"].shape[0],) + pages.shape)
-    return {**block_cache,
-            "self": {"k_pool": sp["k"], "v_pool": sp["v"], "pages": pg}}
+    return {**block_cache, "self": {"k_pool": pools["k"], "v_pool": pools["v"],
+                                    "pages": pages, "layer": layer}}
 
 
 def _apply_cache_update(old_layer_cache, upd, pos, *, pages=None,
-                        page_size=None, update_mask=None):
-    """Apply a block's cache update to an UNSTACKED layer cache."""
+                        page_size=None, update_mask=None, write_paged):
+    """Apply a block's cache update to an UNSTACKED layer cache.
+    ``write_paged``: the paged K/V write (``_write_kv_paged`` or
+    ``_write_kv_pages``)."""
     if upd is None:
         return old_layer_cache
     out = {}
@@ -500,9 +533,9 @@ def _apply_cache_update(old_layer_cache, upd, pos, *, pages=None,
         if key == "self" and _is_delta(val):
             if pages is not None:
                 out["self"] = {
-                    kk: _write_kv_paged(old_layer_cache["self"][kk],
-                                        val[f"{kk}_delta"], pos, pages,
-                                        page_size, stacked=False)
+                    kk: write_paged(old_layer_cache["self"][kk],
+                                    val[f"{kk}_delta"], pos, pages,
+                                    page_size, stacked=False)
                     for kk in ("k", "v")}
             else:
                 out["self"] = {
@@ -519,7 +552,7 @@ def _apply_cache_update(old_layer_cache, upd, pos, *, pages=None,
 
 
 def _apply_stacked_updates(stacked, updates, pos, *, pages=None,
-                           page_size=None, update_mask=None):
+                           page_size=None, update_mask=None, write_paged):
     """Apply scan-collected per-layer updates to a stacked cache.
 
     KV deltas (G,B,KV,S,D) are written with ONE dynamic-update-slice at the
@@ -532,7 +565,8 @@ def _apply_stacked_updates(stacked, updates, pos, *, pages=None,
     harmless (paged decode writes them to the trash page), but SSM state is
     a dense per-slot buffer with no page indirection — a mid-prefill slot's
     carried state must not be advanced by interleaved decode steps of the
-    live batch (DESIGN.md §9)."""
+    live batch (DESIGN.md §9).  ``write_paged`` as in
+    ``_apply_cache_update``."""
     if updates is None:
         return stacked
     new = dict(stacked)
@@ -540,9 +574,9 @@ def _apply_stacked_updates(stacked, updates, pos, *, pages=None,
         if key == "self" and _is_delta(val):
             if pages is not None:
                 new["self"] = {
-                    kk: _write_kv_paged(stacked["self"][kk],
-                                        val[f"{kk}_delta"], pos, pages,
-                                        page_size, stacked=True)
+                    kk: write_paged(stacked["self"][kk],
+                                    val[f"{kk}_delta"], pos, pages,
+                                    page_size, stacked=True)
                     for kk in ("k", "v")}
             else:
                 new["self"] = {
@@ -630,8 +664,9 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens, *,
     pools; reads gather per-slot views through the page table, writes
     scatter through it.  ``paged_impl``: resolved through
     ``resolve_paged_impl`` — on ``kernel``/``interpret`` the S == 1 decode
-    path skips the gather entirely and the paged flash-decode kernel
-    indexes the pools through the page table (DESIGN.md §15); ``ref``
+    path skips the gather entirely: the paged flash-decode kernel reads
+    each layer's pages in place through the page table, and the new K/V
+    is written by whole pages (DESIGN.md §15); ``ref``
     (and ``auto`` off-TPU) keeps ``gather_pages`` as the oracle path.
     ``valid_len``: true prompt length of a bucketed
     (right-padded) prefill — masks SSM state updates past the true end.
@@ -672,21 +707,6 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens, *,
                             paged_impl, cfg.kv_cache_dtype or cfg.compute_dtype,
                             cfg.compute_dtype) != "ref")
 
-    def group_body(carry, xs):
-        h = carry
-        gparams, gcache = xs
-        updates = []
-        for i, kind in enumerate(plan.pattern):
-            p = (params.get("shared") if kind == "shared"
-                 else _constrain_block_params(gparams[i]))
-            h, nc, _ = apply_block(cfg, kind, p, h, positions=positions,
-                                   enc_out=enc_out, cache=gcache[i],
-                                   cache_len=pos, impl=impl,
-                                   paged_impl=paged_impl,
-                                   valid_len=valid_len)
-            updates.append(nc)
-        return h, tuple(updates)
-
     groups = tuple(params["groups"])
     gcaches = tuple(cache["groups"])
     # paged: gather each slot's pages into dense-layout K/V views ONCE per
@@ -694,32 +714,62 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens, *,
     # group), so the blocks read a view indistinguishable from a dense
     # cache; writes go through the page table into the pools afterwards.
     # On the paged-kernel decode path no view is materialised at all: the
-    # pools pass straight through and the kernel's index map IS the gather.
+    # stacked pools stay out of the scan's xs (a layer's slice handed to
+    # the kernel would be copied out whole), the body closes over them,
+    # and the kernel copies the pages of the layer the xs index names.
+    # That path writes whole pages, in the pools' own layout; the gather
+    # path, which also runs sharded over a mesh, keeps the row scatter.
     if use_paged_kernel:
-        read_gcaches = tuple(_page_pool_view_block(bc, pages, stacked=True)
-                             for bc in gcaches)
-        read_tail = [_page_pool_view_block(bc, pages, stacked=False)
+        write_paged = _write_kv_pages
+        pools = tuple(_attn_pools(bc) for bc in gcaches)
+        read_gcaches = tuple(
+            bc if pool is None
+            else {k: v for k, v in bc.items() if k != "self"}
+            for bc, pool in zip(gcaches, pools))
+        read_tail = [_page_pool_view_block(bc, pages, _attn_pools(bc), 0)
                      for bc in cache["tail"]]
     else:
+        write_paged = _write_kv_paged
+        pools = (None,) * len(gcaches)
         read_gcaches = _page_views(gcaches, pages)
         read_tail = [_page_view_block(bc, pages) for bc in cache["tail"]]
+
+    def group_body(carry, xs):
+        h = carry
+        gparams, gcache, layer = xs
+        updates = []
+        for i, kind in enumerate(plan.pattern):
+            p = (params.get("shared") if kind == "shared"
+                 else _constrain_block_params(gparams[i]))
+            bc = _page_pool_view_block(gcache[i], pages, pools[i], layer)
+            h, nc, _ = apply_block(cfg, kind, p, h, positions=positions,
+                                   enc_out=enc_out, cache=bc,
+                                   cache_len=pos, impl=impl,
+                                   paged_impl=paged_impl,
+                                   valid_len=valid_len)
+            updates.append(nc)
+        return h, tuple(updates)
+
     if jax.tree.leaves(groups):
         n_groups = jax.tree.leaves(groups)[0].shape[0]
         if cfg.scan_layers and n_groups > 1:
-            x, updates = jax.lax.scan(group_body, x, (groups, read_gcaches))
+            x, updates = jax.lax.scan(
+                group_body, x,
+                (groups, read_gcaches, jnp.arange(n_groups, dtype=jnp.int32)))
         else:
             outs = []
             for g in range(n_groups):
                 gp = jax.tree.map(lambda t: t[g], groups)
                 gc = jax.tree.map(lambda t: t[g], read_gcaches)
-                x, upd = group_body(x, (gp, gc))
+                x, upd = group_body(x, (gp, gc, g))
                 outs.append(upd)
             updates = jax.tree.map(lambda *ts: jnp.stack(ts), *outs) \
                 if outs else None
         new_gcaches = tuple(
             _apply_stacked_updates(gcaches[i], updates[i], pos,
                                    pages=pages, page_size=page_size,
-                                   update_mask=update_mask)
+                                   update_mask=update_mask,
+                                   write_paged=write_paged)
             for i in range(len(plan.pattern)))
     else:
         new_gcaches = gcaches
@@ -733,7 +783,8 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens, *,
                                valid_len=valid_len)
         new_tail.append(_apply_cache_update(cache["tail"][i], nc, pos,
                                             pages=pages, page_size=page_size,
-                                            update_mask=update_mask))
+                                            update_mask=update_mask,
+                                            write_paged=write_paged))
 
     x = apply_norm(cfg, params["norm_f"], x)
     logits = unembed(cfg, params["embed"], x)

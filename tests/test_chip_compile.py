@@ -236,3 +236,77 @@ def test_qwen2_chunk_prefill_step_compiles(one_chip):
         pages_row=_spec(one_chip, (1, n_pages), jnp.int32)).compile()
     logits = compiled.out_info[0]
     assert logits.shape == (1, 1, cfg.padded_vocab)
+
+
+def _scheduled_ops(text: str):
+    """(name, result shape, opcode, first operand) of every instruction in
+    the module's scheduled computations (the entry, loop bodies and
+    conditions), leaving out those inside fusions.  Tuple-shaped
+    instructions (loops, kernel calls) are left out too."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    ops, comp = [], None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if head:
+            comp = head.group(1)
+            continue
+        if comp in fused:
+            continue
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\((.*)", ln)
+        if m:
+            first = re.search(r"%([\w.\-]+)", m.group(4))
+            ops.append((m.group(1), m.group(2), m.group(3),
+                        first.group(1) if first else None, m.group(4)))
+    return ops
+
+
+@pytest.mark.parametrize("arch,slots,pool_pages", [
+    ("qwen2-1.5b", 180, 18_500),
+    ("granite-4.0-h-micro", 32, 10_241),
+])
+def test_paged_decode_step_keeps_the_pool_in_place(one_chip, arch, slots,
+                                                   pool_pages):
+    """The decode step at the serving cells' shapes, with the cache donated
+    as ``PagedEngine`` donates it: no op copies the stacked KV pools or a
+    layer's slice of them (neither the layer scan nor a change of layout
+    around the token write); each pool's one op is the write into it, in
+    its own layout, on the donated buffer.  The kernel appears once, the
+    page table its first operand.  The pools are the cells' own: one of a
+    few MiB the compiler may hold whole in the chip's VMEM, and copy there."""
+    cfg = dataclasses.replace(get_config(arch), param_dtype="bfloat16")
+    max_pages = -(-5120 // PAGE)              # the cells' max_len
+    lane_dense = cfg.hd % 128 != 0
+    params = _on(one_chip, jax.eval_shape(functools.partial(init_params, cfg),
+                                          jax.random.PRNGKey(0)))
+    cache = _on(one_chip, jax.eval_shape(functools.partial(
+        init_paged_cache, cfg, slots, num_pages=pool_pages, page_size=PAGE,
+        lane_dense=lane_dense)))
+    step = functools.partial(decode_step, cfg, page_size=PAGE,
+                             paged_impl="kernel")
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, _spec(one_chip, (slots, 1), jnp.int32),
+        pages=_spec(one_chip, (slots, max_pages), jnp.int32),
+        update_mask=_spec(one_chip, (slots,), jnp.bool_)).compile()
+    text = _hlo_text(compiled)
+
+    pool = next(bc["self"]["k"].shape for bc in cache["groups"]
+                if "self" in bc)
+    dims = lambda shape: "bf16[" + ",".join(map(str, shape)) + "]"
+    shapes = {dims(pool), dims(pool[1:])}
+    ops = _scheduled_ops(text)
+    of_pool = [op for op in ops if op[1].split("{")[0] in shapes
+               and op[2] not in ("parameter", "get-tuple-element", "bitcast")]
+    params_in = {name: (shape, int(re.match(r"(\d+)", rest).group(1)))
+                 for name, shape, opcode, _, rest in ops
+                 if opcode == "parameter" and shape.split("{")[0] in shapes}
+    aliased = {int(n) for n in re.findall(
+        r"\{[\d,]*\}: \((\d+), \{\}", text.split("\n", 1)[0])}
+    writes = [op for op in of_pool
+              if op[3] in params_in and op[1] == params_in[op[3]][0]]
+    assert [op[:3] for op in of_pool if op not in writes] == []
+    assert sorted(op[3] for op in writes) == sorted(params_in)
+    assert all(n in aliased for _, n in params_in.values())
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and f"custom-call(s32[{slots},{max_pages}]" in ln]
+    assert len(calls) == 1

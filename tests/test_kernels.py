@@ -302,6 +302,7 @@ def _paged_case(key, B, H, KV, D, page_size, n_slot_pages, kv_lens, *,
 
 PA_CASES = [
     # B, H, KV, D, page_size, n_slot_pages, kv_lens, window, share, trash
+    # [, (layers, layer, pool head size): the pools stacked over layers]
     (3, 4, 2, 64, 8, 4, (5, 17, 0), None, False, 0),
     (2, 8, 8, 64, 16, 3, (31, 16), None, False, 0),
     (4, 4, 4, 32, 8, 6, (40, 23, 8, 1), 11, False, 0),
@@ -314,22 +315,57 @@ PA_CASES = [
     (2, 4, 2, 32, 8, 6, (48, 41), None, False, 0),       # every table page
     (3, 4, 2, 32, 8, 4, (0, 0, 0), None, False, 0),      # no live slot
     (2, 4, 2, 32, 8, 6, (44, 36), None, False, (1, 2)),  # trash mid-block
+    # every layer's pools stacked, read at one layer in place:
+    (2, 4, 2, 128, 16, 4, (37, 50), None, False, 0, (3, 0, 128)),
+    (2, 4, 2, 128, 16, 4, (37, 50), None, False, 0, (3, 2, 128)),
+    (2, 8, 2, 64, 16, 4, (20, 45), None, False, 0, (3, 0, 128)),  # lane-dense
+    (2, 8, 2, 64, 16, 4, (20, 45), None, False, 0, (3, 2, 128)),
+    (3, 4, 2, 32, 8, 6, (40, 23, 8), 11, False, 0, (3, 0, 32)),
+    (3, 4, 2, 32, 8, 6, (40, 23, 8), 11, False, 0, (3, 2, 32)),
+    (2, 4, 2, 32, 8, 4, (5, 30), None, False, (0,), (3, 0, 32)),  # all trash
+    (2, 4, 2, 32, 8, 4, (5, 30), None, False, (0,), (3, 2, 32)),
 ]
+
+
+def _stacked(key, pool, layers, layer, width):
+    """``layers`` pools of head size ``width`` stacked, ``pool`` padded
+    with zeros at ``layer`` and the others random, NaN on the trash
+    page."""
+    stack = jax.random.normal(key, (layers,) + pool.shape[:-1] + (width,),
+                              pool.dtype).at[:, 0].set(jnp.nan)
+    pad = jnp.pad(pool, [(0, 0)] * (pool.ndim - 1)
+                  + [(0, width - pool.shape[-1])])
+    return stack.at[layer].set(pad)
+
+
 @pytest.mark.parametrize("case", PA_CASES)
 @pytest.mark.parametrize("pages_per_block", [1, 4])
 def test_paged_attention_kernel_matches_ref(case, pages_per_block):
+    """The kernel against the gather oracle; on stacked pools, read at
+    one layer, also bit-equal to the kernel on that layer's slice."""
     from repro.kernels.paged_attention.ops import paged_decode_attention
-    B, H, KV, D, ps, n, lens, window, share, trash = case
+    B, H, KV, D, ps, n, lens, window, share, trash, *stack = case
     q, kp, vp, tbl, kv_len, kt, vt = _paged_case(
         KEYS[3], B, H, KV, D, ps, n, lens, share=share, trash_tail=trash)
     ref = paged_decode_attention(q, kp, vp, tbl, kv_len, kt, vt,
                                  window=window, impl="ref")
+    layer = 0
+    if stack:
+        (layers, layer, width), = stack
+        kp, vp = (_stacked(k, pool, layers, layer, width) for k, pool
+                  in zip(jax.random.split(KEYS[7]), (kp, vp)))
     out = paged_decode_attention(q, kp, vp, tbl, kv_len, kt, vt,
-                                 window=window, impl="interpret",
+                                 layer=layer, window=window,
+                                 impl="interpret",
                                  pages_per_block=pages_per_block)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-6, rtol=2e-6)
+    if stack:
+        one = paged_decode_attention(q, kp[layer], vp[layer], tbl, kv_len,
+                                     kt, vt, window=window, impl="interpret",
+                                     pages_per_block=pages_per_block)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(one))
 
 
 @pytest.mark.parametrize("case", PA_CASES[:4])

@@ -414,3 +414,79 @@ def test_paged_engine_reports_resolved_attention_impl(qwen):
     with pytest.raises(ValueError, match="float8"):
         PagedEngine(f8, params, batch=2, max_len=32, page_size=8,
                     prefill_chunk=16, attn_impl="interpret")
+
+
+# ---------------------------------------------------------------------------
+# The decode write by whole pages (the paged-kernel path)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("stacked", [True, False])
+@pytest.mark.parametrize("seed,width", [(0, 8), (1, 16)])
+def test_page_write_matches_row_write(stacked, seed, width):
+    """``_write_kv_pages`` leaves every page but the trash page bit-equal
+    to the row scatter ``_write_kv_paged``: live slots writing at the first
+    and the last offset of a page, free slots writing to the trash page,
+    and a slot that shares another's pages, its partial page copied first
+    (``copy_page``) — the owner's rows stay as they were.  ``width`` 16
+    pads a head size of 8 (a lane-dense pool)."""
+    from repro.models.transformer import _write_kv_pages, _write_kv_paged
+    rng = np.random.default_rng(seed)
+    G, P, KV, ps, D, n = 3, 16, 2, 4, 8, 4
+    ids = rng.permutation(np.arange(1, P))
+    own0, own1, copy = ids[:3], ids[3:6], ids[6]
+    table = np.zeros((5, n), np.int32)
+    table[0, :3] = own0                 # writes own0[2] at offset 0
+    table[1, :3] = own1                 # writes own1[2] at offset ps - 1
+    table[4, :2] = own0[0], copy        # shares own0[:2]; own0[1] copied
+    pos = np.array([2 * ps, 3 * ps - 1, 5, 2, ps + 2], np.int32)
+    lead = (G,) if stacked else ()
+    pool = jnp.asarray(rng.normal(size=lead + (P, KV, ps, width)),
+                       jnp.bfloat16)
+    pool = pool.at[..., copy, :, :, :].set(pool[..., own0[1], :, :, :])
+    delta = jnp.asarray(rng.normal(size=lead + (5, KV, 1, D)), jnp.float32)
+    args = (pool, delta, jnp.asarray(pos), jnp.asarray(table), ps)
+    got = np.asarray(_write_kv_pages(*args, stacked=stacked), np.float32)
+    want = np.asarray(_write_kv_paged(*args, stacked=stacked), np.float32)
+    np.testing.assert_array_equal(got[..., 1:, :, :, :],
+                                  want[..., 1:, :, :, :])
+    before = np.asarray(pool, np.float32)
+    for b, pid, off in ((0, own0[2], 0), (1, own1[2], ps - 1), (4, copy, 2)):
+        row = np.zeros(lead + (KV, width), np.float32)
+        row[..., :D] = np.asarray(delta[..., b, :, 0, :].astype(pool.dtype),
+                                  np.float32)
+        np.testing.assert_array_equal(got[..., pid, :, off, :], row)
+    untouched = [p for p in range(1, P) if p not in (own0[2], own1[2], copy)]
+    np.testing.assert_array_equal(got[..., untouched, :, :, :],
+                                  before[..., untouched, :, :, :])
+
+
+def test_kernel_decode_matches_gather_decode_tokens(qwen):
+    """A ``PagedEngine`` decoding on the paged kernel (interpret mode, the
+    pools read in place and written by whole pages) gives the gather
+    path's tokens, a free slot writing to the trash page throughout."""
+    cfg, params = qwen
+    rng = np.random.default_rng(5)
+    prompts = [_prompt(rng, cfg, 13), _prompt(rng, cfg, 8)]
+    steps = 5
+
+    def run(impl):
+        eng = PagedEngine(cfg, params, batch=3, max_len=32, page_size=8,
+                          prefill_chunk=16, attn_impl=impl)
+        alloc = PageAllocator(eng.num_pages)
+        toks = np.zeros(3, np.int32)
+        for b, p in enumerate(prompts):
+            pages = alloc.alloc(eng.pages_needed(len(p), steps + 1))
+            lg = eng.insert(b, p, page_ids=pages, max_new=steps + 1)
+            toks[b] = int(jnp.argmax(lg[0, -1]))
+        outs, logits = [toks.copy()], []
+        for _ in range(steps):
+            lg = eng.decode(jnp.asarray(toks)[:, None])
+            logits.append(np.asarray(lg[:2, -1]))
+            toks = np.asarray(jnp.argmax(lg[:, -1, :], -1), np.int32)
+            outs.append(toks)
+        return np.stack(outs, axis=1)[:2], np.stack(logits)
+
+    (ref_toks, ref_lg), (ker_toks, ker_lg) = run("ref"), run("interpret")
+    np.testing.assert_array_equal(ker_toks, ref_toks)
+    np.testing.assert_allclose(ker_lg, ref_lg, atol=1e-4, rtol=1e-4)

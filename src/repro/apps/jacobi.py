@@ -23,8 +23,9 @@ identical, and the exact final residual is recomputed once outside the
 timed loop for reporting.
 
 Paper's claim (Fig. 3): the framework stays within ~10 % (mean) of the
-tailored runtime at sizes 2709/4209/7209, 500 iterations.
-``benchmarks/jacobi_paper.py`` reproduces that table.
+tailored runtime at sizes 2709/4209/7209, 500 iterations.  The benchmark
+cell ``jacobi-7209.paper`` (``bench/``) times this solver at the largest
+size on the chip.
 """
 from __future__ import annotations
 

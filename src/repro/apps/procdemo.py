@@ -6,8 +6,7 @@ cheap: plain numpy functions at module level, with the master-side registry
 and graph builders importing the heavy core lazily.
 
 The workload is a chain of chunkwise matmul+tanh segments over a fixed
-weight (the dispatch-overhead shape of ``benchmarks/hypar_overhead.py``)
-ending in a whole-kind reduction — enough structure to exercise placement,
+weight ending in a whole-kind reduction — enough structure to exercise placement,
 pipelining, memoisation and crash recovery, deterministic end to end.
 
 ``REPRO_PROCDEMO_SLEEP`` (seconds, float) slows every worker function down;
@@ -68,8 +67,7 @@ def make_registry(host: bool = False):
     ``host=True`` instead registers numpy whole-kind wrappers with the same
     zip-over-chunks semantics, so the SAME graph runs on LocalExecutor's
     worker threads (whose chunkwise path jits, which a host numpy function
-    cannot survive) bit-identically to the process children — the thread
-    baseline of ``benchmarks/hypar_overhead.run_proc_dispatch``."""
+    cannot survive) bit-identically to the process children."""
     from repro.core import (ChunkedData, DataChunk, FunctionKind,
                             FunctionRegistry)
     reg = FunctionRegistry()

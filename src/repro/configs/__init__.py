@@ -5,7 +5,7 @@ from __future__ import annotations
 
 import importlib
 
-from repro.models.config import SHAPE_CELLS, ModelConfig, ShapeCell
+from repro.models.config import ModelConfig
 
 _MODULES = {
     "whisper-base": "whisper_base",
@@ -37,11 +37,3 @@ def get_config(arch: str) -> ModelConfig:
 def get_smoke_config(arch: str) -> ModelConfig:
     return _mod(arch).SMOKE
 
-
-def cells_for(arch: str) -> tuple[str, ...]:
-    """Shape cells assigned to this arch (long_500k per DESIGN.md §4)."""
-    return _mod(arch).CELLS
-
-
-def all_cells() -> list[tuple[str, str]]:
-    return [(a, c) for a in ARCHS for c in cells_for(a)]

@@ -14,5 +14,3 @@ CONFIG = ModelConfig(
 SMOKE = dataclasses.replace(
     CONFIG, name="chameleon-34b-smoke", n_layers=3, d_model=64, n_heads=4,
     n_kv_heads=2, d_ff=128, vocab_size=512, max_seq=256)
-
-CELLS = ("train_4k", "prefill_32k", "decode_32k")

@@ -12,5 +12,3 @@ CONFIG = ModelConfig(
 SMOKE = dataclasses.replace(
     CONFIG, name="deepseek-coder-33b-smoke", n_layers=3, d_model=64,
     n_heads=4, n_kv_heads=2, d_ff=160, vocab_size=512, max_seq=256)
-
-CELLS = ("train_4k", "prefill_32k", "decode_32k")

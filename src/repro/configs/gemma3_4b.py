@@ -16,6 +16,3 @@ SMOKE = dataclasses.replace(
     CONFIG, name="gemma3-4b-smoke", n_layers=6, d_model=64, n_heads=4,
     n_kv_heads=2, d_ff=128, vocab_size=512, head_dim=16,
     local_global_ratio=2, local_window=16, max_seq=256)
-
-# sub-quadratic (5/6 of layers local-1024): long_500k runs
-CELLS = ("train_4k", "prefill_32k", "decode_32k", "long_500k")

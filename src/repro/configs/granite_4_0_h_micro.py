@@ -24,6 +24,3 @@ SMOKE = dataclasses.replace(
     CONFIG, name="granite-4.0-h-micro-smoke", n_layers=20, d_model=64,
     n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=512, ssm_state=16,
     ssm_head_dim=16, ssm_chunk=16, attn_scale=1.0 / 16, max_seq=256)
-
-# a served model: not one of the assigned dry-run shape cells
-CELLS = ()

@@ -27,5 +27,3 @@ SMOKE = dataclasses.replace(
     CONFIG, name="llama3-405b-smoke", n_layers=3, d_model=128, n_heads=8,
     n_kv_heads=2, d_ff=256, vocab_size=512, optimizer="adamw", max_seq=256,
     kv_cache_dtype="")  # smoke tests check exact decode parity; f8 is a serving choice
-
-CELLS = ("train_4k", "prefill_32k", "decode_32k")

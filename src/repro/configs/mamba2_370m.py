@@ -13,5 +13,3 @@ CONFIG = ModelConfig(
 SMOKE = dataclasses.replace(
     CONFIG, name="mamba2-370m-smoke", n_layers=3, d_model=64,
     vocab_size=512, ssm_state=16, ssm_head_dim=16, ssm_chunk=16, max_seq=256)
-
-CELLS = ("train_4k", "prefill_32k", "decode_32k", "long_500k")  # SSM: runs long

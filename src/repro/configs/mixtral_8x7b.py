@@ -15,6 +15,3 @@ SMOKE = dataclasses.replace(
     CONFIG, name="mixtral-8x7b-smoke", n_layers=2, d_model=64, n_heads=4,
     n_kv_heads=2, d_ff=128, vocab_size=512, n_experts=4, top_k=2,
     moe_d_ff=96, sliding_window=16, max_seq=256)
-
-# SWA => sub-quadratic: long_500k runs
-CELLS = ("train_4k", "prefill_32k", "decode_32k", "long_500k")

@@ -12,5 +12,3 @@ CONFIG = ModelConfig(
 SMOKE = dataclasses.replace(
     CONFIG, name="qwen2-1.5b-smoke", n_layers=3, d_model=96, n_heads=4,
     n_kv_heads=2, d_ff=192, vocab_size=512, max_seq=256)
-
-CELLS = ("train_4k", "prefill_32k", "decode_32k")  # pure full attention: no long_500k

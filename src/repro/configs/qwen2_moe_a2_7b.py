@@ -15,5 +15,3 @@ SMOKE = dataclasses.replace(
     CONFIG, name="qwen2-moe-a2.7b-smoke", n_layers=2, d_model=64, n_heads=4,
     n_kv_heads=4, d_ff=96, vocab_size=512, n_experts=8, top_k=2,
     moe_d_ff=96, n_shared_experts=2, max_seq=256)
-
-CELLS = ("train_4k", "prefill_32k", "decode_32k")

@@ -16,6 +16,3 @@ SMOKE = dataclasses.replace(
     CONFIG, name="whisper-base-smoke", n_layers=2, n_encoder_layers=2,
     d_model=64, n_heads=4, n_kv_heads=4, d_ff=128, vocab_size=512,
     max_seq=256, decoder_len=32)
-
-# long_500k skipped: decoder context architecturally capped (DESIGN.md §4)
-CELLS = ("train_4k", "prefill_32k", "decode_32k")

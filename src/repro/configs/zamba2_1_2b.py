@@ -18,5 +18,3 @@ SMOKE = dataclasses.replace(
     CONFIG, name="zamba2-1.2b-smoke", n_layers=5, d_model=64, n_heads=4,
     n_kv_heads=4, d_ff=128, vocab_size=512, ssm_state=16, ssm_head_dim=16,
     ssm_chunk=16, hybrid_attn_every=2, max_seq=256)
-
-CELLS = ("train_4k", "prefill_32k", "decode_32k", "long_500k")  # hybrid: runs long

@@ -151,8 +151,8 @@ class BaseExecutor(abc.ABC):
     results directory plus an :class:`ExecutionReport`.
 
     Implementations differ in *where* jobs run (per-device workers, SPMD
-    mesh, …) but not in the contract, so ``launch/``, ``benchmarks/`` and
-    ``apps/`` code is runtime-agnostic.
+    mesh, …) but not in the contract, so ``launch/`` and ``apps/`` code is
+    runtime-agnostic.
     """
 
     registry: FunctionRegistry
@@ -722,8 +722,7 @@ class SpmdExecutor(BaseExecutor):
         The paper expresses iteration by letting a control job re-enqueue the
         body segments; host round-trips per iteration are the price.  On TPU
         we fuse body+condition into one ``lax.while_loop`` so the loop never
-        leaves the device.  Both paths are benchmarked in
-        ``benchmarks/jacobi_paper.py``.
+        leaves the device.
         """
         key = ("iterative", id(spec))
         if key not in self._compiled:

@@ -216,10 +216,8 @@ class Placement:
 class CostModelParams:
     """Hardware constants for the cost-model placement strategy.
 
-    The three terms mirror the roofline decomposition of
-    ``repro.analysis.roofline`` (compute / memory / interconnect); use
-    :meth:`from_hw` to derive them from an ``analysis.roofline.HW`` profile
-    (e.g. ``V5E``).  Defaults are a conservative host-CPU profile so the
+    The three terms are a roofline decomposition (compute / memory /
+    interconnect).  Defaults are a conservative host-CPU profile so the
     model produces sane *relative* costs out of the box.
     """
 
@@ -227,13 +225,6 @@ class CostModelParams:
     mem_bw: float = 20e9          # B/s local (worker-resident) reads
     link_bw: float = 5e9          # B/s cross-worker transfers
     dispatch_s: float = 50e-6     # fixed per-job dispatch overhead
-
-    @classmethod
-    def from_hw(cls, hw) -> "CostModelParams":
-        """Build from any object with peak_flops / hbm_bw / ici_bw attrs
-        (duck-typed so core never imports repro.analysis)."""
-        return cls(peak_flops=hw.peak_flops, mem_bw=hw.hbm_bw,
-                   link_bw=hw.ici_bw)
 
 
 class MasterScheduler:

@@ -6,9 +6,8 @@ holds when the inner loops run at machine speed — and the best block
 shape is a property of the *machine*, not the code (OpenFPM makes the
 same point for reusable frameworks).  This module provides
 
-* a **block-size search**: time each candidate config with the
-  ``_time``-style harness used by ``benchmarks/kernel_bench`` and keep the
-  fastest (:meth:`Autotuner.tune`),
+* a **block-size search**: time each candidate config on the current
+  backend and keep the fastest (:meth:`Autotuner.tune`),
 * a **persistent JSON cache** keyed by ``(kernel, backend, shape-bucket,
   dtype)`` so the search runs once per machine (:class:`TuningCache`;
   corrupt or truncated cache files are discarded, never fatal),
@@ -22,9 +21,9 @@ same point for reusable frameworks).  This module provides
   observed-time table (:func:`observed_fn_times` in ``apps/jacobi``), so
   placement uses observed rather than roofline-guessed costs.
 
-Tuning itself is driven from outside jit (``benchmarks/kernel_bench``,
-``benchmarks/run --suite kernels``); timing inside a trace would record
-tracing time, not kernel time.
+Tuning itself must be driven from outside jit; timing inside a trace would
+record tracing time, not kernel time.  Nothing in the repository runs a
+search today, so the wrappers' lookups miss and use the default blocks.
 """
 from __future__ import annotations
 

@@ -1,104 +1,13 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
+"""Placement preview of a paper-format job file (§3.3 grammar).
 
-"""Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
-
-The FIRST two lines above run before any other import — JAX locks the
-device count at first initialisation, and the dry-run needs 512 placeholder
-host devices to build the production meshes (16×16 single-pod, 2×16×16
-multi-pod).  Do NOT import this module from tests (they must see 1 device).
-
-Per cell it prints ``compiled.memory_analysis()`` (proves fit),
-``compiled.cost_analysis()`` and the scan-corrected roofline terms
-(repro.analysis), and appends a JSON record to the results file.
+Parses the file and runs the master scheduler segment by segment without
+executing anything, printing each job's worker.
 
 Usage::
 
-    python -m repro.launch.dryrun --arch qwen2-1.5b --cell train_4k
-    python -m repro.launch.dryrun --all [--multi-pod] [--out out.jsonl]
+    python -m repro.launch.dryrun --jobfile jobs.txt --workers 4 --strategy cost
 """
 import argparse
-import json
-import sys
-import time
-import traceback
-
-import jax
-
-from repro.analysis.hlo import xla_cost_analysis
-from repro.analysis.roofline import V5E, roofline_from_compiled
-from repro.configs import ARCHS, cells_for, get_config
-from repro.launch.mesh import make_production_mesh
-from repro.launch.specs import build_cell, rules_for_cell
-from repro.models.config import SHAPE_CELLS
-from repro.parallel.sharding import use_rules
-
-
-def run_cell(arch: str, cell_name: str, *, multi_pod: bool = False,
-             verbose: bool = True) -> dict:
-    cfg = get_config(arch)
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    n_chips = mesh.devices.size
-    cell = SHAPE_CELLS[cell_name]
-    rules = rules_for_cell(mesh, cell, cfg)
-    t0 = time.time()
-    with use_rules(mesh, rules.rules):
-        spec = build_cell(arch, cfg, cell_name, rules)
-        jitted = jax.jit(spec.fn, in_shardings=spec.in_shardings,
-                         out_shardings=spec.out_shardings,
-                         donate_argnums=spec.donate_argnums)
-        lowered = jitted.lower(*spec.args)
-        compiled = lowered.compile()
-    t_compile = time.time() - t0
-
-    mem = compiled.memory_analysis()
-    cost = xla_cost_analysis(compiled)
-    terms = roofline_from_compiled(compiled, hw=V5E, n_chips=n_chips,
-                                   model_flops=spec.model_flops)
-    arg_b = getattr(mem, "argument_size_in_bytes", 0)
-    out_b = getattr(mem, "output_size_in_bytes", 0)
-    tmp_b = getattr(mem, "temp_size_in_bytes", 0)
-    alias_b = getattr(mem, "alias_size_in_bytes", 0)
-    per_dev = arg_b + out_b + tmp_b - alias_b
-    fits = per_dev <= V5E.hbm_bytes
-
-    rec = {
-        "arch": arch, "cell": cell_name,
-        "mesh": "2x16x16" if multi_pod else "16x16",
-        "n_chips": n_chips, "grad_accum": spec.grad_accum,
-        "compile_s": round(t_compile, 1),
-        "bytes_per_device": per_dev, "fits_hbm": bool(fits),
-        "arg_bytes": arg_b, "temp_bytes": tmp_b, "alias_bytes": alias_b,
-        "hlo_flops_per_dev": terms.flops,
-        "hlo_traffic_per_dev": terms.traffic_bytes,
-        "collective_bytes_per_dev": terms.collective_bytes,
-        "collective_counts": terms.analysis.collectives.counts,
-        "compute_s": terms.compute_s, "memory_s": terms.memory_s,
-        "collective_s": terms.collective_s, "dominant": terms.dominant,
-        "model_flops": spec.model_flops,
-        "useful_ratio": (spec.model_flops / n_chips) / terms.flops
-        if terms.flops else 0.0,
-        "roofline_fraction": ((spec.model_flops / n_chips) / terms.step_s)
-        / V5E.peak_flops if terms.step_s else 0.0,
-        "while_trips": terms.analysis.while_trips,
-        "xla_flops_per_dev": cost.get("flops"),
-    }
-    if verbose:
-        print(f"== {arch} × {cell_name} × {rec['mesh']} "
-              f"(compile {t_compile:.0f}s, accum={spec.grad_accum})")
-        print(f"   memory_analysis: args={arg_b/2**30:.2f}GiB "
-              f"temp={tmp_b/2**30:.2f}GiB alias={alias_b/2**30:.2f}GiB "
-              f"per-dev={per_dev/2**30:.2f}GiB fits16G={fits}")
-        print(f"   roofline: compute={terms.compute_s*1e3:.2f}ms "
-              f"memory={terms.memory_s*1e3:.2f}ms "
-              f"collective={terms.collective_s*1e3:.2f}ms "
-              f"dominant={terms.dominant} "
-              f"useful={rec['useful_ratio']*100:.1f}% "
-              f"roofline_frac={rec['roofline_fraction']*100:.1f}%")
-        print(f"   collectives: " + " ".join(
-            f"{k}:{v}" for k, v in rec["collective_counts"].items() if v))
-    return rec
 
 
 def plan_jobfile(path: str, *, n_workers: int = 4, cores: int = 1,
@@ -136,60 +45,13 @@ def plan_jobfile(path: str, *, n_workers: int = 4, cores: int = 1,
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=list(ARCHS))
-    ap.add_argument("--cell", choices=list(SHAPE_CELLS))
-    ap.add_argument("--all", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--both-meshes", action="store_true")
-    ap.add_argument("--out", default="")
-    ap.add_argument("--jobfile", default="",
-                    help="placement dry-run of a paper-format job file "
-                         "instead of the arch x cell compile sweep")
+    ap.add_argument("--jobfile", required=True,
+                    help="placement dry-run of a paper-format job file")
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--strategy", choices=["greedy", "cost"], default="cost")
     args = ap.parse_args(argv)
-
-    if args.jobfile:
-        plan_jobfile(args.jobfile, n_workers=args.workers,
-                     strategy=args.strategy)
-        return
-
-    cells = []
-    if args.all:
-        for a in ARCHS:
-            for c in cells_for(a):
-                cells.append((a, c))
-    elif args.arch and args.cell:
-        cells = [(args.arch, args.cell)]
-    elif args.arch:
-        cells = [(args.arch, c) for c in cells_for(args.arch)]
-    else:
-        ap.error("need --arch [--cell] or --all")
-
-    meshes = [args.multi_pod]
-    if args.both_meshes:
-        meshes = [False, True]
-
-    failures = []
-    out_f = open(args.out, "a") if args.out else None
-    for arch, cell in cells:
-        for mp in meshes:
-            try:
-                rec = run_cell(arch, cell, multi_pod=mp)
-                if out_f:
-                    out_f.write(json.dumps(rec) + "\n")
-                    out_f.flush()
-            except Exception as e:
-                failures.append((arch, cell, mp, repr(e)))
-                traceback.print_exc()
-    if out_f:
-        out_f.close()
-    if failures:
-        print(f"\n{len(failures)} FAILURES:")
-        for f in failures:
-            print("  ", f)
-        sys.exit(1)
-    print(f"\nall {len(cells) * len(meshes)} cells compiled OK")
+    return plan_jobfile(args.jobfile, n_workers=args.workers,
+                        strategy=args.strategy)
 
 
 if __name__ == "__main__":

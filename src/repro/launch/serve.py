@@ -1,4 +1,8 @@
-"""Serving driver: batched requests against a (reduced) model.
+"""Serving CLI: drive the serving stack by hand on a full or ``--smoke``
+model and print what it did.
+
+The benchmark's numbers come from ``bench/run.py`` (``BENCHMARK.json``);
+the times printed here are one run's wall clock, a quick look only.
 
 Two modes:
 
@@ -162,7 +166,7 @@ def prepare_trace(cfg, params, args, *, sp: SamplingParams):
     """Build a warmed scheduler + the request trace for it.
 
     Warmup runs on the SAME scheduler: Engine jit caches are per-instance,
-    so a throwaway warmup engine would leave the measured replays to pay
+    so a throwaway warmup engine would leave the measured replay to pay
     every prefill/decode/splice compilation they claim to have excluded.
     """
     max_len = max(args.prompt_lens) + args.max_new + 8
@@ -192,34 +196,93 @@ def prepare_trace(cfg, params, args, *, sp: SamplingParams):
     # second, compile-free pass: the first pass's steps are dominated by
     # compiles, and the step/retire EWMAs (which deliberately survive
     # reset_metrics as shedding calibration, DESIGN.md §14) would otherwise
-    # enter the measured replays 100-1000x above steady state
+    # enter the measured replay 100-1000x above steady state
     sched.run(warmup_requests(rng, cfg, prompt_lens=list(args.prompt_lens)))
     sched.reset_metrics()
     if getattr(sched, "prefix_cache_active", False):
         # drop the warmup prompts' cache entries (and their held pages):
-        # measured replays start from a cold cache and earn their hits from
+        # the measured replay starts from a cold cache and earns its hits from
         # the trace's own shared prefixes
         sched.flush_prefix_cache()
     return sched, reqs
 
 
-def replay_trace(sched, reqs) -> tuple:
-    """One measured replay of ``reqs`` on a warmed scheduler.  Returns a
-    ``(tok_per_s, results, wall, occupancy, n_rejected)`` snapshot and
-    resets the scheduler's metrics for the next replay.  (``run()`` rebases
-    each request's arrival onto the live clock, so every replay gets fresh
-    Request copies.)"""
+def run_trace(cfg, params, args, *, sp: SamplingParams) -> dict:
+    """One measured replay of the trace on a warmed scheduler, and its
+    stats.  (``run()`` rebases each request's arrival onto the live clock,
+    so the replay gets fresh Request copies.)"""
+    sched, reqs = prepare_trace(cfg, params, args, sp=sp)
+    if getattr(args, "resume", False):
+        # master restart: re-seed suspended-request records from the durable
+        # store — resubmitted rids resume by recompute (DESIGN.md §12)
+        n = sched.restore_suspended()
+        print(f"restored {n} suspended request(s) from {args.store}")
     replay = [dataclasses.replace(r) for r in reqs]
     t0 = time.perf_counter()
     results = sched.run(replay)
     wall = time.perf_counter() - t0
-    rate = sum(r.n_generated for r in results) / wall if wall > 0 else 0.0
-    # preempt/defer counters ride in the snapshot: reset_metrics() clears
-    # them on the scheduler, so trace_stats cannot read them post hoc
+    return trace_stats(args, sched, results, wall)
+
+
+def trace_stats(args, sched, results, wall: float) -> dict:
+    """The stats dict of a replay: ``results`` and its ``wall`` seconds,
+    and the scheduler's counters since its last ``reset_metrics``."""
+    n_tok = sum(r.n_generated for r in results)
+    # NaN, not 0.0, when nothing completed: a broken/all-shed run must not
+    # report perfect-looking latencies
+    ttfts = (np.array([r.ttft_s for r in results]) if results
+             else np.array([np.nan]))
+    lats = (np.array([l for r in results for l in r.step_latencies_s])
+            if any(r.step_latencies_s for r in results)
+            else np.array([np.nan]))
     outcome_hist: dict[str, int] = {}
     for o in sched.outcomes.values():
         outcome_hist[o.outcome] = outcome_hist.get(o.outcome, 0) + 1
-    robust = {
+    n_lookups, n_hits = sched.n_prefix_lookups, sched.n_prefix_hits
+    eng = sched.engine
+    trace_counts = ({"chunk_prefill": eng.trace_count("chunk_prefill"),
+                     "decode": eng.trace_count("decode")}
+                    if sched.paged else
+                    {"prefill": eng.trace_count("prefill"),
+                     "decode": eng.trace_count("decode"),
+                     "splice": eng.trace_count("splice")})
+    stats = {
+        "engine": args.engine,
+        "paged": sched.paged,
+        "n_requests": len(results),
+        "n_rejected": sched.queue.n_rejected,
+        "gen_tokens": n_tok,
+        "wall_s": wall,
+        "tok_per_s": n_tok / wall if wall > 0 else 0.0,
+        "ttft_p50_s": float(np.percentile(ttfts, 50)),
+        "ttft_p95_s": float(np.percentile(ttfts, 95)),
+        "lat_p50_s": float(np.percentile(lats, 50)),
+        "lat_p95_s": float(np.percentile(lats, 95)),
+        "occupancy": sched.occupancy,
+        "trace_counts": trace_counts,
+        # the decode attention the run actually executed (PagedEngine
+        # resolves "auto" from the backend and the pool dtype)
+        "paged_attn_impl": eng.attn_impl if sched.paged else None,
+        # recurrent state beside the pages: bytes a slot holds, and the
+        # prefills that started a reused slot's state from zero
+        "ssm_state_bytes": eng.ssm_state_bytes if sched.paged else None,
+        "ssm_state_resets": eng.ssm_state_resets if sched.paged else None,
+        # generated ids per request: what mesh/impl parity checks compare
+        "outputs": {int(r.rid): [int(t) for t in r.tokens] for r in results},
+        "reserve": getattr(sched, "reserve", "lifetime"),
+        "preempt_count": sched.n_preempted,
+        "resume_tokens_recomputed": sched.resume_tokens_recomputed,
+        "admit_deferred": sched.n_admit_deferred,
+        "prefix_cache": sched.prefix_cache_active,
+        "prefix_hit_rate": (n_hits / n_lookups if n_lookups else 0.0),
+        "pages_shared": sched.pages_shared,
+        "cow_copies": sched.n_cow_copies,
+        "cache_insert_deferred": sched.n_cache_insert_deferred,
+        "mesh": getattr(args, "mesh", None) or None,
+        "device_groups": len(sched.groups),
+        "group_occupancy": [float(x) for x in sched.group_occupancy],
+        # robustness surface (DESIGN.md §14): typed shed counters, terminal
+        # outcome histogram, deadline goodput, watchdog/failover counts
         "shed_queue_full": sched.queue.shed_queue_full,
         "shed_never_fits": sched.queue.shed_never_fits,
         "shed_deadline": sched.queue.shed_deadline,
@@ -232,96 +295,7 @@ def replay_trace(sched, reqs) -> tuple:
         "group_rejoins": sched.n_group_rejoins,
         "rejoin_backoff_s": sched.rejoin_backoff_s,
         "suspended_rids": sorted(sched._suspended),
-    }
-    snap = (rate, results, wall, sched.occupancy, sched.queue.n_rejected,
-            sched.n_preempted, sched.resume_tokens_recomputed,
-            sched.n_admit_deferred, sched.n_prefix_lookups,
-            sched.n_prefix_hits, sched.pages_shared, sched.n_cow_copies,
-            sched.n_cache_insert_deferred, tuple(sched.group_occupancy),
-            robust)
-    sched.reset_metrics()              # also clears occupancy + counters
-    return snap
-
-
-def run_trace(cfg, params, args, *, sp: SamplingParams,
-              repeats: int = 1) -> dict:
-    sched, reqs = prepare_trace(cfg, params, args, sp=sp)
-    if getattr(args, "resume", False):
-        # master restart: re-seed suspended-request records from the durable
-        # store — resubmitted rids resume by recompute (DESIGN.md §12)
-        n = sched.restore_suspended()
-        print(f"restored {n} suspended request(s) from {args.store}")
-    # ``repeats``: replay the SAME trace N times on the warmed scheduler and
-    # keep the fastest replay — the serve benchmark's noise floor on shared
-    # CI/CPU boxes is far above the engine differences it wants to resolve,
-    # and best-of-N is the same discipline kernel_bench applies per-op.
-    # (benchmarks/serve_bench.py goes further and ROUND-ROBINS the replays
-    # of the engines it compares, so minute-scale machine drift cannot land
-    # entirely on one engine's measurements.)
-    snaps = [replay_trace(sched, reqs) for _ in range(max(1, repeats))]
-    return trace_stats(args, sched, max(snaps, key=lambda s: s[0]))
-
-
-def trace_stats(args, sched, snap) -> dict:
-    """Build the stats dict from the best replay snapshot."""
-    (_, results, wall, occupancy, n_rejected,
-     n_preempted, resume_recomputed, n_deferred,
-     n_lookups, n_hits, pages_shared, cow_copies,
-     cache_insert_deferred, group_occupancy, robust) = snap
-    n_tok = sum(r.n_generated for r in results)
-    # NaN, not 0.0, when nothing completed: a broken/all-shed run must not
-    # record perfect-looking latencies into the BENCH trajectory
-    ttfts = (np.array([r.ttft_s for r in results]) if results
-             else np.array([np.nan]))
-    lats = (np.array([l for r in results for l in r.step_latencies_s])
-            if any(r.step_latencies_s for r in results)
-            else np.array([np.nan]))
-    eng = sched.engine
-    trace_counts = ({"chunk_prefill": eng.trace_count("chunk_prefill"),
-                     "decode": eng.trace_count("decode")}
-                    if sched.paged else
-                    {"prefill": eng.trace_count("prefill"),
-                     "decode": eng.trace_count("decode"),
-                     "splice": eng.trace_count("splice")})
-    stats = {
-        "engine": args.engine,
-        "paged": sched.paged,
-        "n_requests": len(results),
-        "n_rejected": n_rejected,
-        "gen_tokens": n_tok,
-        "wall_s": wall,
-        "tok_per_s": n_tok / wall if wall > 0 else 0.0,
-        "ttft_p50_s": float(np.percentile(ttfts, 50)),
-        "ttft_p95_s": float(np.percentile(ttfts, 95)),
-        "lat_p50_s": float(np.percentile(lats, 50)),
-        "lat_p95_s": float(np.percentile(lats, 95)),
-        "occupancy": occupancy,
-        "trace_counts": trace_counts,
-        # the decode attention the run actually executed (PagedEngine
-        # resolves "auto" from the backend and the pool dtype)
-        "paged_attn_impl": eng.attn_impl if sched.paged else None,
-        # recurrent state beside the pages: bytes a slot holds, and the
-        # prefills that started a reused slot's state from zero
-        "ssm_state_bytes": eng.ssm_state_bytes if sched.paged else None,
-        "ssm_state_resets": eng.ssm_state_resets if sched.paged else None,
-        # generated ids per request: what mesh/impl parity checks compare
-        "outputs": {int(r.rid): [int(t) for t in r.tokens] for r in results},
-        "reserve": getattr(sched, "reserve", "lifetime"),
-        "preempt_count": n_preempted,
-        "resume_tokens_recomputed": resume_recomputed,
-        "admit_deferred": n_deferred,
-        "prefix_cache": sched.prefix_cache_active,
-        "prefix_hit_rate": (n_hits / n_lookups if n_lookups else 0.0),
-        "pages_shared": pages_shared,
-        "cow_copies": cow_copies,
-        "cache_insert_deferred": cache_insert_deferred,
-        "mesh": getattr(args, "mesh", None) or None,
-        "device_groups": len(sched.groups),
-        "group_occupancy": [float(x) for x in group_occupancy],
-        # robustness surface (DESIGN.md §14): typed shed counters, terminal
-        # outcome histogram, deadline goodput, watchdog/failover counts
-        **robust,
-        "goodput_tok_per_s": (robust["goodput_tokens"] / wall
+        "goodput_tok_per_s": (sched.goodput_tokens / wall
                               if wall > 0 else 0.0),
         "enforce_deadlines": getattr(sched, "enforce_deadlines", True),
     }
@@ -456,12 +430,6 @@ def main(argv=None):
                          "slots + page pool over DP device groups "
                          "(DESIGN.md §13).  '1,1' forces the mesh code "
                          "path on one device (bit-identical to no mesh)")
-    ap.add_argument("--repeats", type=int, default=1,
-                    help="trace mode: replay the trace N times on the "
-                         "warmed scheduler and report the fastest")
-    ap.add_argument("--stats-json", default="",
-                    help="trace mode: dump the stats dict to this path as "
-                         "JSON (the sharded bench reads it back)")
     ap.add_argument("--shared-prefix-len", type=int, default=0,
                     help="trace mode: every prompt opens with the same "
                          "token prefix of this length (system-prompt "
@@ -553,7 +521,7 @@ def main(argv=None):
     sp = SamplingParams(temperature=args.temperature)
 
     if args.trace:
-        stats = run_trace(cfg, params, args, sp=sp, repeats=args.repeats)
+        stats = run_trace(cfg, params, args, sp=sp)
         kind = "paged" if stats["paged"] else "dense"
         print(f"engine={stats['engine']} ({kind}) "
               f"requests={stats['n_requests']} "
@@ -593,10 +561,6 @@ def main(argv=None):
               f"lat p50={stats['lat_p50_s']*1e3:.1f}ms "
               f"p95={stats['lat_p95_s']*1e3:.1f}ms "
               f"occupancy={stats['occupancy']*100:.0f}%")
-        if args.stats_json:
-            import json
-            with open(args.stats_json, "w") as f:
-                json.dump(stats, f, indent=1, default=float)
         _maybe_store_gc(args, live_rids=stats.get("suspended_rids", ()))
         return stats
     run_waves(cfg, params, args, sp=sp)

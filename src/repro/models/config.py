@@ -9,7 +9,7 @@ import dataclasses
 import math
 from typing import Any
 
-__all__ = ["ModelConfig", "ShapeCell", "SHAPE_CELLS", "round_up"]
+__all__ = ["ModelConfig", "round_up"]
 
 
 def round_up(x: int, m: int) -> int:
@@ -173,25 +173,3 @@ class ModelConfig:
         ew = self.moe_d_ff or self.d_ff
         dead = 3 * d * ew * (self.n_experts - self.top_k) * self.n_layers
         return self.n_params() - int(dead)
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeCell:
-    """One assigned (input-shape) cell."""
-
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str          # train | prefill | decode
-
-    @property
-    def tokens(self) -> int:
-        return self.seq_len * self.global_batch
-
-
-SHAPE_CELLS = {
-    "train_4k": ShapeCell("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeCell("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeCell("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeCell("long_500k", 524_288, 1, "decode"),
-}

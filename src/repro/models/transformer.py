@@ -429,35 +429,14 @@ def _pad_lanes(x, width: int):
     return x if pad == 0 else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
-def _write_kv_paged(pool, delta, pos, pages, page_size, *, stacked: bool):
-    """Scatter one decode step's K/V delta into a shared page pool.
+def _write_kv_pages(pool, delta, pos, pages, page_size, *, stacked: bool):
+    """Write one decode step's K/V delta into a shared page pool, by whole
+    pages in the pool's own layout.
 
     pool: (G, P, KV, ps, D) stacked or (P, KV, ps, D) unstacked; delta the
     matching (…, B, KV, 1, D); pos (B,) per-slot token positions; pages
     (B, n_pages) page table.  Logical position ``pos`` of slot ``b`` lives
-    in physical page ``pages[b, pos // ps]`` at offset ``pos % ps``.  Slots
-    never share pages (allocator invariant), so the scatter indices are
-    unique across live slots; free slots all map to the reserved trash page,
-    whose contents are never validly read.
-    """
-    pos = jnp.broadcast_to(jnp.asarray(pos), (pages.shape[0],))
-    B = pages.shape[0]
-    pid = jnp.take_along_axis(pages, (pos // page_size)[:, None],
-                              axis=1)[:, 0]                       # (B,)
-    off = pos % page_size
-    val = _pad_lanes(delta.astype(pool.dtype), pool.shape[-1])
-    if stacked:
-        # (G, B, KV, 1, D) -> (B, G, KV, D); advanced indices (pid, off)
-        # are separated by slices, so the batch axis moves to the front
-        val = jnp.moveaxis(val[:, :, :, 0, :], 1, 0)
-        out = pool.at[:, pid, :, off, :].set(val)
-        return logical(out, None, "pages", "kv_heads", None, None)
-    out = pool.at[pid, :, off, :].set(val[:, :, 0, :])
-    return logical(out, "pages", "kv_heads", None, None)
-
-
-def _write_kv_pages(pool, delta, pos, pages, page_size, *, stacked: bool):
-    """``_write_kv_paged`` by whole pages, in the pool's own layout.
+    in physical page ``pages[b, pos // ps]`` at row ``pos % ps``.
 
     Each slot's current page is gathered, the new token's row set in it,
     and the pages scattered back whole at their ids: the scatter indexes
@@ -466,7 +445,8 @@ def _write_kv_pages(pool, delta, pos, pages, page_size, *, stacked: bool):
     in-page offset makes XLA convert the whole pool to another layout and
     back.  Live slots never share the page they write (a shared partial
     page is copied first), so the pages scattered are distinct, but for
-    the trash page, where every free slot writes.
+    the trash page, where every free slot writes and whose contents are
+    never validly read.
     """
     pos = jnp.broadcast_to(jnp.asarray(pos), (pages.shape[0],))
     pid = jnp.take_along_axis(pages, (pos // page_size)[:, None],
@@ -522,10 +502,8 @@ def _page_pool_view_block(block_cache, pages, pools, layer):
 
 
 def _apply_cache_update(old_layer_cache, upd, pos, *, pages=None,
-                        page_size=None, update_mask=None, write_paged):
-    """Apply a block's cache update to an UNSTACKED layer cache.
-    ``write_paged``: the paged K/V write (``_write_kv_paged`` or
-    ``_write_kv_pages``)."""
+                        page_size=None, update_mask=None):
+    """Apply a block's cache update to an UNSTACKED layer cache."""
     if upd is None:
         return old_layer_cache
     out = {}
@@ -533,7 +511,7 @@ def _apply_cache_update(old_layer_cache, upd, pos, *, pages=None,
         if key == "self" and _is_delta(val):
             if pages is not None:
                 out["self"] = {
-                    kk: write_paged(old_layer_cache["self"][kk],
+                    kk: _write_kv_pages(old_layer_cache["self"][kk],
                                     val[f"{kk}_delta"], pos, pages,
                                     page_size, stacked=False)
                     for kk in ("k", "v")}
@@ -552,12 +530,12 @@ def _apply_cache_update(old_layer_cache, upd, pos, *, pages=None,
 
 
 def _apply_stacked_updates(stacked, updates, pos, *, pages=None,
-                           page_size=None, update_mask=None, write_paged):
+                           page_size=None, update_mask=None):
     """Apply scan-collected per-layer updates to a stacked cache.
 
     KV deltas (G,B,KV,S,D) are written with ONE dynamic-update-slice at the
-    token position (or one per slot for per-slot ``pos`` vectors; one
-    scatter through the page table for paged pools); SSM states come out of
+    token position (or one per slot for per-slot ``pos`` vectors; whole
+    pages scattered through the page table for paged pools); SSM states come out of
     the scan already whole, stacked — they simply replace the old buffers.
 
     ``update_mask`` (B,) bool: slots whose NON-delta state (SSM conv tail +
@@ -565,8 +543,7 @@ def _apply_stacked_updates(stacked, updates, pos, *, pages=None,
     harmless (paged decode writes them to the trash page), but SSM state is
     a dense per-slot buffer with no page indirection — a mid-prefill slot's
     carried state must not be advanced by interleaved decode steps of the
-    live batch (DESIGN.md §9).  ``write_paged`` as in
-    ``_apply_cache_update``."""
+    live batch (DESIGN.md §9)."""
     if updates is None:
         return stacked
     new = dict(stacked)
@@ -574,7 +551,7 @@ def _apply_stacked_updates(stacked, updates, pos, *, pages=None,
         if key == "self" and _is_delta(val):
             if pages is not None:
                 new["self"] = {
-                    kk: write_paged(stacked["self"][kk],
+                    kk: _write_kv_pages(stacked["self"][kk],
                                     val[f"{kk}_delta"], pos, pages,
                                     page_size, stacked=True)
                     for kk in ("k", "v")}
@@ -661,13 +638,13 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens, *,
     """One cache-extending step.  tokens: (B, S) int32 (or embeds (B,S,d));
     S == 1 is decode, S > 1 is batched prefill (cache must be fresh).
     ``pages``/``page_size``: the cache's attention buffers are shared page
-    pools; reads gather per-slot views through the page table, writes
-    scatter through it.  ``paged_impl``: resolved through
-    ``resolve_paged_impl`` — on ``kernel``/``interpret`` the S == 1 decode
-    path skips the gather entirely: the paged flash-decode kernel reads
-    each layer's pages in place through the page table, and the new K/V
-    is written by whole pages (DESIGN.md §15); ``ref``
-    (and ``auto`` off-TPU) keeps ``gather_pages`` as the oracle path.
+    pools; reads gather per-slot views through the page table, and the new
+    K/V is written by whole pages through it (DESIGN.md §15).
+    ``paged_impl``: resolved through ``resolve_paged_impl`` — on
+    ``kernel``/``interpret`` the S == 1 decode path skips the gather
+    entirely: the paged flash-decode kernel reads each layer's pages in
+    place through the page table; ``ref`` (and ``auto`` off-TPU) keeps
+    ``gather_pages`` as the oracle path.
     ``valid_len``: true prompt length of a bucketed
     (right-padded) prefill — masks SSM state updates past the true end.
     ``update_mask`` (B,) bool: freeze the per-slot SSM state of masked-out
@@ -717,10 +694,8 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens, *,
     # stacked pools stay out of the scan's xs (a layer's slice handed to
     # the kernel would be copied out whole), the body closes over them,
     # and the kernel copies the pages of the layer the xs index names.
-    # That path writes whole pages, in the pools' own layout; the gather
-    # path, which also runs sharded over a mesh, keeps the row scatter.
+    # Both paths write whole pages, in the pools' own layout.
     if use_paged_kernel:
-        write_paged = _write_kv_pages
         pools = tuple(_attn_pools(bc) for bc in gcaches)
         read_gcaches = tuple(
             bc if pool is None
@@ -729,7 +704,6 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens, *,
         read_tail = [_page_pool_view_block(bc, pages, _attn_pools(bc), 0)
                      for bc in cache["tail"]]
     else:
-        write_paged = _write_kv_paged
         pools = (None,) * len(gcaches)
         read_gcaches = _page_views(gcaches, pages)
         read_tail = [_page_view_block(bc, pages) for bc in cache["tail"]]
@@ -768,8 +742,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens, *,
         new_gcaches = tuple(
             _apply_stacked_updates(gcaches[i], updates[i], pos,
                                    pages=pages, page_size=page_size,
-                                   update_mask=update_mask,
-                                   write_paged=write_paged)
+                                   update_mask=update_mask)
             for i in range(len(plan.pattern)))
     else:
         new_gcaches = gcaches
@@ -783,8 +756,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens, *,
                                valid_len=valid_len)
         new_tail.append(_apply_cache_update(cache["tail"][i], nc, pos,
                                             pages=pages, page_size=page_size,
-                                            update_mask=update_mask,
-                                            write_paged=write_paged))
+                                            update_mask=update_mask))
 
     x = apply_norm(cfg, params["norm_f"], x)
     logits = unembed(cfg, params["embed"], x)

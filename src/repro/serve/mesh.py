@@ -15,7 +15,7 @@ One :class:`~repro.serve.engine.PagedEngine` instance spans a device mesh
   caching, COW and preemption never cross a group boundary.
 
 The constraints themselves live in the model code as ``logical(...)``
-annotations (``gather_pages``, ``_write_kv_paged``/``_write_kv_chunk_paged``)
+annotations (``gather_pages``, ``_write_kv_pages``/``_write_kv_chunk_paged``)
 that are no-ops outside a ``use_rules`` context — single-device serving
 compiles byte-identical HLO to before.  A mesh of total size 1 resolves
 every rule to a trivial (fully-replicated) spec, so mesh==1 is bit-identical

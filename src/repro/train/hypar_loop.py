@@ -16,8 +16,8 @@ treedefs are closed over by the registered functions (workers are "fat":
 they contain all user functions, paper §3.2).
 
 The fused SPMD step (repro/train/step.py) is the "tailored" implementation
-this is benchmarked against — reproducing the shape of the paper's Fig. 3
-experiment on the LM workload (see benchmarks/hypar_overhead.py).
+this is compared against — the shape of the paper's Fig. 3 experiment on
+the LM workload.
 """
 from __future__ import annotations
 

@@ -4,8 +4,8 @@
 what the paper's framework would assemble from the job graph
 (DATA → GRAD×microbatches (no_send_back) → OPT); the HyPar-scheduled
 variant that literally goes through the JobGraph/SpmdExecutor lives in
-``repro/train/hypar_loop.py`` and is benchmarked against this fused step in
-``benchmarks/`` (framework-vs-tailored, the paper's Fig. 3 experiment shape).
+``repro/train/hypar_loop.py`` and is compared against this fused step
+(framework-vs-tailored, the paper's Fig. 3 experiment shape).
 """
 from __future__ import annotations
 

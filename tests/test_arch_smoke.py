@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import ARCHS, cells_for, get_config, get_smoke_config
+from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.models.transformer import (decode_step, forward, init_cache,
                                       init_params, loss_fn, param_count)
 from repro.optim import OptimizerSpec
@@ -107,13 +107,3 @@ def test_moe_active_params():
     qm = get_config("qwen2-moe-a2.7b")
     assert 2.2e9 <= qm.active_params() <= 3.2e9
 
-
-def test_cell_assignment_documented():
-    """34 runnable cells + 6 documented long_500k skips = 40 assigned."""
-    total = sum(len(cells_for(a)) for a in ARCHS)
-    assert total == 34
-    for a in ("mamba2-370m", "zamba2-1.2b", "gemma3-4b", "mixtral-8x7b"):
-        assert "long_500k" in cells_for(a)
-    for a in ("qwen2-1.5b", "llama3-405b", "whisper-base", "chameleon-34b",
-              "deepseek-coder-33b", "qwen2-moe-a2.7b"):
-        assert "long_500k" not in cells_for(a)

@@ -28,7 +28,7 @@ from repro.kernels.ssd_scan.ops import ssd_intra_chunk
 from repro.models.transformer import (chunk_prefill_step, decode_step,
                                       init_paged_cache, init_params)
 
-BATCH, PAGE, MAX_LEN = 8, 16, 280          # chip_smoke.py's serving shapes
+BATCH, PAGE, MAX_LEN = 8, 16, 280          # a small paged serving shape
 
 
 @pytest.fixture(scope="module")

@@ -425,38 +425,43 @@ def test_paged_engine_reports_resolved_attention_impl(qwen):
 @pytest.mark.parametrize("seed,width", [(0, 8), (1, 16)])
 def test_page_write_matches_row_write(stacked, seed, width):
     """``_write_kv_pages`` leaves every page but the trash page bit-equal
-    to the row scatter ``_write_kv_paged``: live slots writing at the first
-    and the last offset of a page, free slots writing to the trash page,
-    and a slot that shares another's pages, its partial page copied first
-    (``copy_page``) — the owner's rows stay as they were.  ``width`` 16
-    pads a head size of 8 (a lane-dense pool)."""
-    from repro.models.transformer import _write_kv_pages, _write_kv_paged
+    to a numpy oracle that sets row ``pos % ps`` of page
+    ``table[b, pos // ps]`` for each live slot ``b``: live slots writing at
+    the first and the last row of a page, free slots writing to the trash
+    page, and a slot that shares another's pages, its partial page copied
+    first (``copy_page``) — the owner's rows stay as they were.  ``width``
+    16 pads a head size of 8 (a lane-dense pool)."""
+    from repro.models.transformer import _write_kv_pages
     rng = np.random.default_rng(seed)
     G, P, KV, ps, D, n = 3, 16, 2, 4, 8, 4
     ids = rng.permutation(np.arange(1, P))
     own0, own1, copy = ids[:3], ids[3:6], ids[6]
     table = np.zeros((5, n), np.int32)
-    table[0, :3] = own0                 # writes own0[2] at offset 0
-    table[1, :3] = own1                 # writes own1[2] at offset ps - 1
+    table[0, :3] = own0                 # writes own0[2] at row 0
+    table[1, :3] = own1                 # writes own1[2] at row ps - 1
     table[4, :2] = own0[0], copy        # shares own0[:2]; own0[1] copied
     pos = np.array([2 * ps, 3 * ps - 1, 5, 2, ps + 2], np.int32)
+    live = (0, 1, 4)                    # slots 2 and 3 are free: trash page
     lead = (G,) if stacked else ()
     pool = jnp.asarray(rng.normal(size=lead + (P, KV, ps, width)),
                        jnp.bfloat16)
     pool = pool.at[..., copy, :, :, :].set(pool[..., own0[1], :, :, :])
     delta = jnp.asarray(rng.normal(size=lead + (5, KV, 1, D)), jnp.float32)
-    args = (pool, delta, jnp.asarray(pos), jnp.asarray(table), ps)
-    got = np.asarray(_write_kv_pages(*args, stacked=stacked), np.float32)
-    want = np.asarray(_write_kv_paged(*args, stacked=stacked), np.float32)
+    got = np.asarray(_write_kv_pages(pool, delta, jnp.asarray(pos),
+                                     jnp.asarray(table), ps,
+                                     stacked=stacked), np.float32)
+    before = np.asarray(pool, np.float32)
+    rows = np.asarray(delta.astype(pool.dtype), np.float32)
+    want = before.copy()
+    for b in live:
+        pid, off = table[b, pos[b] // ps], pos[b] % ps
+        want[..., pid, :, off, :D] = rows[..., b, :, 0, :]
+        want[..., pid, :, off, D:] = 0.0
     np.testing.assert_array_equal(got[..., 1:, :, :, :],
                                   want[..., 1:, :, :, :])
-    before = np.asarray(pool, np.float32)
-    for b, pid, off in ((0, own0[2], 0), (1, own1[2], ps - 1), (4, copy, 2)):
-        row = np.zeros(lead + (KV, width), np.float32)
-        row[..., :D] = np.asarray(delta[..., b, :, 0, :].astype(pool.dtype),
-                                  np.float32)
-        np.testing.assert_array_equal(got[..., pid, :, off, :], row)
-    untouched = [p for p in range(1, P) if p not in (own0[2], own1[2], copy)]
+    written = {int(table[b, pos[b] // ps]) for b in live}
+    assert written == {int(own0[2]), int(own1[2]), int(copy)}
+    untouched = [p for p in range(1, P) if p not in written]
     np.testing.assert_array_equal(got[..., untouched, :, :, :],
                                   before[..., untouched, :, :, :])
 
